@@ -108,9 +108,7 @@ def direct_vmem_ooc_gemm(A, B, C, alpha, beta, block=(256, 256, 256),
     its own grid, BlockSpecs, scratch and padding logic."""
     import functools
     from jax.experimental import pallas as pl
-
-    from repro.compat import tpu_memory_space
-    _ms = tpu_memory_space()
+    from jax.experimental.pallas import tpu as pltpu
 
     bm, bn, bk = block
     M, K = A.shape
@@ -148,7 +146,7 @@ def direct_vmem_ooc_gemm(A, B, C, alpha, beta, block=(256, 256, 256),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), C.dtype),
-        scratch_shapes=[_ms.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.MemorySpace.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(Ap, Bp, Cp)
     return out[:M, :N]

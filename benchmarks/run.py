@@ -62,8 +62,10 @@ def main() -> None:
                             bench_loc, bench_overhead, bench_pipeline,
                             bench_reuse, bench_roofline, bench_simulate,
                             bench_transition, bench_tune, bench_validate)
+    from repro.compile_cache import enable_compile_cache
     from repro.obs import get_observability
 
+    enable_compile_cache()
     obs = get_observability()
     print("name,us_per_call,derived")
     failures = 0

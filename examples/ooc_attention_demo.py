@@ -34,9 +34,11 @@ expect = ref.decode_attention_ref(
 print(f"engine max err vs oracle: "
       f"{np.abs(np.asarray(out) - np.asarray(expect)).max():.2e}")
 
-# the same computation through the Pallas kernel (interpret mode on CPU)
+# the same computation through the Pallas kernel (interpret mode on CPU),
+# which takes the cache head-major: (B, Hkv, S, d)
 out_k = ops.flash_decode_attention(
-    jnp.asarray(q)[None], jnp.asarray(k)[None], jnp.asarray(v)[None],
+    jnp.asarray(q)[None], jnp.asarray(k).transpose(1, 0, 2)[None],
+    jnp.asarray(v).transpose(1, 0, 2)[None],
     jnp.asarray([S]), block_s=512, interpret=True)[0]
 print(f"pallas max err vs oracle: "
       f"{np.abs(np.asarray(out_k) - np.asarray(expect)).max():.2e}")

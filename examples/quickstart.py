@@ -5,9 +5,9 @@ skipped gracefully).  ~30 s.
 """
 import numpy as np
 
-from repro.core import (build_gemm_schedule, gpu_like, ooc_gemm,
-                        plan_gemm_partition, schedule_stats, simulate,
-                        tpu_v5e_vmem, validate_schedule)
+from repro.core import (VmemOocRuntime, build_gemm_schedule, gpu_like,
+                        ooc_gemm, plan_gemm_partition, schedule_stats,
+                        simulate, tpu_v5e_vmem, validate_schedule)
 
 rng = np.random.default_rng(0)
 M, N, K = 768, 640, 512
@@ -33,7 +33,8 @@ out = ooc_gemm(A, B, C, 1.5, 0.5, budget_bytes=budget, backend="host")
 print(f"host backend max err: {np.abs(out - ref).max():.2e}")
 
 # 4. execute through the Pallas VMEM kernel (interpret mode on CPU)
-out_v = ooc_gemm(A, B, C, 1.5, 0.5, budget_bytes=budget, backend="vmem")
+out_v = ooc_gemm(A, B, C, 1.5, 0.5, budget_bytes=budget, backend="vmem",
+                 runtime=VmemOocRuntime(interpret=True))
 print(f"vmem backend max err: {np.abs(np.asarray(out_v) - ref).max():.2e}")
 
 # 5. what would this schedule do on real hardware?  (engine model)

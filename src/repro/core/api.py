@@ -25,11 +25,12 @@ from repro.core.runtime import (
     register_op_handler,
 )
 from repro.core.streams import Device, Schedule, Stream, StreamFactory
+from repro.kernels.block_matmul import VMEM_SCOPED_LIMIT
 
 # Device-type names map to memory tiers (DESIGN.md §2): the analogues of the
 # paper's {"GPU", "PHI", "FPGA"} triple.
 _TIER_BYTES = {
-    "VMEM": 128 * 2**20,   # v5e VMEM
+    "VMEM": VMEM_SCOPED_LIMIT,  # v5e scoped VMEM per Pallas kernel
     "HBM": 16 * 2**30,     # v5e HBM
     "MESH": 16 * 2**30,    # per-shard HBM (aggregate = pod)
     "HYBRID": 0,           # composite: memory is the member devices' sum

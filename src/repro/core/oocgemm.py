@@ -198,6 +198,14 @@ def ooc_gemm(
         out, _ = run_hybrid_gemm(A, B, C, alpha, beta, hplan,
                                  validate=validate)
         return out
+    if backend == "mesh":
+        # operands go from where they are straight to their shards, never
+        # whole onto the default device first
+        if C is None:
+            C = np.zeros((A.shape[0], B.shape[1]), dtype=A.dtype)
+            beta = 0.0
+        rt = runtime or MeshOocRuntime(mesh)
+        return rt.gemm(A, B, C, alpha, beta, None)
     A = np.asarray(A) if backend == "host" else jnp.asarray(A)
     B = np.asarray(B) if backend == "host" else jnp.asarray(B)
     M, K = A.shape
@@ -209,10 +217,6 @@ def ooc_gemm(
             else jnp.zeros((M, N), dtype=A.dtype)
         beta = 0.0
     bpe = np.dtype(A.dtype).itemsize
-
-    if backend == "mesh":
-        rt = runtime or MeshOocRuntime(mesh)
-        return rt.gemm(A, B, C, alpha, beta, None)
 
     if is_in_core(M, N, K, budget_bytes, bpe):
         # In-core fast path: one resident DGEMM (claim C2 transition point).

@@ -166,6 +166,17 @@ def _take(arr, ref: SliceRef):
     return arr.T if ref.transpose else arr
 
 
+def _put_block(host_block) -> jax.Array:
+    """One H2D transfer, landed on return.  The TPU runtime stages H2D
+    copies in a fixed premapped host buffer (4 GiB by default).  Transfers
+    left in flight while the host's issue loop runs ahead overflow it, and
+    a strided block (a column block of a row-major operand) that does not
+    fit there takes a host staging copy as large as itself.  Waiting keeps
+    one transfer in flight.  Compute dispatched earlier keeps running
+    meanwhile."""
+    return jnp.asarray(host_block).block_until_ready()
+
+
 def _spans_overlap(a: SliceRef, b: SliceRef, shape) -> bool:
     def hit(sa, sb, extent):
         lo_a, n_a = sa if sa is not None else (0, extent)
@@ -422,7 +433,7 @@ class ScheduleExecutor:
                 for k in [k for k, (_, pref) in pending.items()
                           if _spans_overlap(ref, pref, src_shape)]:
                     flush_retrying(k)
-            st.bufs[key] = jnp.asarray(_take(st.host(ref.operand), ref))
+            st.bufs[key] = _put_block(_take(st.host(ref.operand), ref))
             if fi is not None:   # fresh load = host-consistent snapshot
                 clean[key] = st.bufs[key]
                 chains[key] = []
@@ -617,8 +628,8 @@ class ScheduleExecutor:
         dispatching op ``i`` it waits the ``threading.Event`` of every
         cross-engine predecessor in ``plan.preds[i]`` (same-engine edges
         are implied by the queue walk) and sets ``done[i]`` after the op
-        completed *on this engine* — H2D after the device put was issued,
-        D2H after the block landed in host memory, COMPUTE after the
+        completed *on this engine* — H2D after the block landed on the
+        device, D2H after it landed in host memory, COMPUTE after the
         handler dispatched.  This mirrors the simulator's event program:
         engines block, the host never does.
 
@@ -671,7 +682,7 @@ class ScheduleExecutor:
             kind = plan.kinds[i]
             if kind == _xplan.KIND_H2D:
                 eng_h2d[e] += op.bytes
-                st.bufs[op.buffers_written[0]] = jnp.asarray(
+                st.bufs[op.buffers_written[0]] = _put_block(
                     _take(st.host(ref.operand), ref))
             elif kind == _xplan.KIND_COMPUTE:
                 handler_at(i, ref)(st, op, ref)
@@ -918,13 +929,13 @@ class VmemOocRuntime(OocRuntime):
     A/B/C tile DMAs across grid steps)."""
 
     def __init__(self, device: Optional[Device] = None,
-                 interpret: Optional[bool] = None):
-        self.device = device or Device("VMEM", 0, 128 * 2**20)
-        # CPU container: interpret mode (kernel body runs in Python).
-        self.interpret = (
-            interpret if interpret is not None
-            else jax.devices()[0].platform != "tpu"
-        )
+                 interpret: bool = False):
+        from repro.kernels.block_matmul import VMEM_SCOPED_LIMIT
+        from repro.kernels.ops import check_interpret
+
+        self.device = device or Device("VMEM", 0, VMEM_SCOPED_LIMIT)
+        # off a TPU the caller must choose Pallas interpret mode explicitly
+        self.interpret = check_interpret(interpret)
 
     def gemm(self, A, B, C, alpha, beta, part: GemmPartition,
              block: Optional[Tuple[int, int, int]] = None, **kw):
@@ -967,53 +978,68 @@ class MeshOocRuntime(OocRuntime):
         return cls(mesh, device=device, **kw)
 
     def gemm(self, A, B, C, alpha, beta, part=None, overlap: bool = True, **kw):
-        mesh, axis = self.mesh, self.axis
-        Pn = mesh.shape[axis]
-        M, K = A.shape
+        Pn = self.mesh.shape[self.axis]
+        M, _ = A.shape
         _, N = B.shape
         if M % Pn or N % Pn:
             raise ValueError(f"SUMMA needs M,N divisible by mesh axis {Pn}")
-        n_blk = N // Pn
-        alpha = jnp.float32(alpha)
-        beta = jnp.float32(beta)
+        args = [jax.device_put(x, s)
+                for x, s in zip((A, B, C), self.shardings())]
+        return self.program(overlap)(*args, jnp.float32(alpha),
+                                     jnp.float32(beta))
 
-        def ring_body(a_blk, b_blk, c_blk):
-            # a_blk: (M/P, K)  b_blk: (K, N/P)  c_blk: (M/P, N)
-            me = jax.lax.axis_index(axis)
-            perm = [(i, (i - 1) % Pn) for i in range(Pn)]
+    def shardings(self) -> Tuple[NamedSharding, ...]:
+        """Shardings of ``(A, B, C)``: A and C by row blocks, B by column
+        blocks over the mesh axis."""
+        return _summa_shardings(self.mesh, self.axis)
 
-            def step(t, carry):
-                b_cur, acc = carry
-                # issue the permute FIRST so Mosaic/XLA can overlap the ICI
-                # transfer of the next block with this block's matmul
-                # (ping-pong buffer: b_nxt is a fresh buffer).
-                b_nxt = jax.lax.ppermute(b_cur, axis, perm) if overlap else b_cur
-                col = ((me + t) % Pn) * n_blk
-                prod = jnp.dot(a_blk, b_cur,
-                               preferred_element_type=jnp.float32)
-                old = jax.lax.dynamic_slice(
-                    acc, (0, col), (acc.shape[0], n_blk))
-                upd = (alpha * prod + beta * old).astype(acc.dtype)
-                acc = jax.lax.dynamic_update_slice(acc, upd, (0, col))
-                if not overlap:
-                    b_nxt = jax.lax.ppermute(b_cur, axis, perm)
-                return b_nxt, acc
+    def program(self, overlap: bool = True):
+        """The jitted SUMMA ring ``(A, B, C, alpha, beta) -> C`` over this
+        runtime's mesh axis (one per mesh/axis/overlap, so repeated calls
+        reuse its compilation)."""
+        return _summa_program(self.mesh, self.axis, overlap)
 
-            _, acc = jax.lax.fori_loop(0, Pn, step, (b_blk, c_blk))
-            return acc
 
-        spec_a = P(axis, None)
-        spec_b = P(None, axis)
-        spec_c = P(axis, None)
-        fn = jax.shard_map(
-            ring_body, mesh=mesh,
-            in_specs=(spec_a, spec_b, spec_c),
-            out_specs=spec_c,
-        )
-        sA = jax.device_put(A, NamedSharding(mesh, spec_a))
-        sB = jax.device_put(B, NamedSharding(mesh, spec_b))
-        sC = jax.device_put(C, NamedSharding(mesh, spec_c))
-        return jax.jit(fn)(sA, sB, sC)
+def _summa_shardings(mesh: Mesh, axis: str) -> Tuple[NamedSharding, ...]:
+    return tuple(NamedSharding(mesh, s)
+                 for s in (P(axis, None), P(None, axis), P(axis, None)))
+
+
+@functools.lru_cache(maxsize=16)
+def _summa_program(mesh: Mesh, axis: str, overlap: bool):
+    Pn = mesh.shape[axis]
+
+    def ring_body(a_blk, b_blk, c_blk, alpha, beta):
+        # a_blk: (M/P, K)  b_blk: (K, N/P)  c_blk: (M/P, N)
+        n_blk = b_blk.shape[1]
+        me = jax.lax.axis_index(axis)
+        perm = [(i, (i - 1) % Pn) for i in range(Pn)]
+
+        def step(t, carry):
+            b_cur, acc = carry
+            # issue the permute FIRST so XLA can overlap the ICI transfer
+            # of the next block with this block's matmul (ping-pong
+            # buffer: b_nxt is a fresh buffer).
+            b_nxt = jax.lax.ppermute(b_cur, axis, perm) if overlap else b_cur
+            col = ((me + t) % Pn) * n_blk
+            prod = jnp.dot(a_blk, b_cur, preferred_element_type=jnp.float32)
+            old = jax.lax.dynamic_slice(acc, (0, col), (acc.shape[0], n_blk))
+            upd = (alpha * prod + beta * old).astype(acc.dtype)
+            acc = jax.lax.dynamic_update_slice(acc, upd, (0, col))
+            if not overlap:
+                b_nxt = jax.lax.ppermute(b_cur, axis, perm)
+            return b_nxt, acc
+
+        _, acc = jax.lax.fori_loop(0, Pn, step, (b_blk, c_blk))
+        return acc
+
+    shardings = _summa_shardings(mesh, axis)
+    specs = tuple(s.spec for s in shardings)
+    fn = jax.shard_map(ring_body, mesh=mesh, in_specs=specs + (P(), P()),
+                       out_specs=specs[2])
+    scalar = NamedSharding(mesh, P())
+    return jax.jit(fn, in_shardings=shardings + (scalar, scalar),
+                   out_shardings=shardings[2])
 
 
 class RuntimeFactory:

@@ -252,14 +252,16 @@ def _rebalance_lost_bands(kernel: str, hplan: HybridPlan,
                           validate: bool) -> None:
     """Recompute every lost device's C row band on the survivors.
 
-    Recovery is exact, not approximate: the band restarts from the
-    ORIGINAL ``C[lo:hi]`` (the dead executor may have partially written
-    ``out``'s band, but ``out`` is a copy so ``C`` is pristine), and the
-    re-balanced sub-GEMM never splits K, so every C block is still one
-    full-depth dot — bitwise identical to the fault-free run regardless of
-    how the survivors' bands differ from the lost device's.  SYRK bands
-    recover through the same path with ``B = P^T`` (identical operand bits
-    into the identical dgemm kernel).  The recursive run is fault-free by
+    The band restarts from the ORIGINAL ``C[lo:hi]`` (the dead executor
+    may have partially written ``out``'s band, but ``out`` is a copy so
+    ``C`` is pristine), and the re-balanced sub-GEMM never splits K, so
+    every C block is still one full-depth dot over the same operand bits.
+    It is not bitwise equal to the fault-free run: the survivors' blocks
+    have other shapes than the lost device's, and the backend's dot may
+    sum a K-long product in a different order per block shape, so the
+    two agree to fp32 summation order (``kernels/ref.gemm_error_bound``).
+    SYRK bands recover through the same path with ``B = P^T``.  The
+    recursive run is fault-free by
     construction: the ``device_lost`` occurrence was consumed by the dead
     job.  Survivors' spans gain a ``(rebalance <dead>)`` lane-group suffix.
     """
@@ -344,7 +346,8 @@ def run_hybrid_syrk(P, C, alpha: float, beta: float, hplan: HybridPlan,
 
     ``fault_plans``/``fault_policy`` behave as in :func:`run_hybrid_gemm`;
     a lost device's band re-balances as the equivalent GEMM with
-    ``B = P^T`` (same operand bits, same dgemm kernel — bitwise).
+    ``B = P^T`` (same operand bits, same dgemm kernel; equal to the
+    fault-free run up to fp32 summation order).
     """
     P = np.asarray(P)
     n, K = P.shape

@@ -27,10 +27,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params, tpu_memory_space
 
-_MS = tpu_memory_space()
-_CP = tpu_compiler_params()
+# Scoped VMEM a Mosaic kernel may use by default on TPU v5e (the chip has
+# 128 MiB physically; the compiler refuses kernels above this limit).
+VMEM_SCOPED_LIMIT = 16 * 2**20
+
+
+def vmem_working_set_bytes(block: Tuple[int, int, int], a_dtype, b_dtype,
+                           c_dtype) -> int:
+    """VMEM one grid step holds: the a, b, c and out blocks, each double
+    buffered by Mosaic's pipeline, plus the single fp32 accumulator."""
+    bm, bn, bk = block
+    size = lambda dt: jnp.dtype(dt).itemsize
+    blocks = (bm * bk * size(a_dtype) + bk * bn * size(b_dtype)
+              + 2 * bm * bn * size(c_dtype))
+    return 2 * blocks + bm * bn * 4
 
 
 def _kernel(a_ref, b_ref, c_ref, out_ref, acc_ref, *, alpha, beta, k_steps):
@@ -78,15 +89,21 @@ def block_matmul(
     Shapes: a (M, K), b (K, N), c (M, N).  Any M/N/K — inputs are zero-padded
     up to block multiples (zero K-padding contributes nothing to the sum).
 
-    VMEM working set per grid step (bf16 in, fp32 acc), default 512³ blocks:
-    a 0.5 MB + b 0.5 MB + c 0.5 MB + out 0.5 MB + acc 1 MB ≈ 3 MB, ×2 for
-    Mosaic's double buffering ≈ 6 MB ≪ 128 MB VMEM — leaves headroom for
-    deeper pipelining (the nbuf > 2 regime of DESIGN.md §2).
+    The block's VMEM working set (:func:`vmem_working_set_bytes`) must fit
+    :data:`VMEM_SCOPED_LIMIT`; a larger block raises ``ValueError`` here
+    instead of ``RESOURCE_EXHAUSTED`` from the chip's compiler.  Default
+    512³ blocks: 9 MiB for fp32 operands, 7 MiB for bf16 with an fp32 C.
     """
     M, K = a.shape
     K2, N = b.shape
     assert K == K2 and c.shape == (M, N), (a.shape, b.shape, c.shape)
     bm, bn, bk = block
+    need = vmem_working_set_bytes(block, a.dtype, b.dtype, c.dtype)
+    if need > VMEM_SCOPED_LIMIT:
+        raise ValueError(
+            f"block {block} needs {need / 2**20:.2f} MiB of VMEM "
+            f"(double-buffered a/b/c/out + fp32 accumulator), over the "
+            f"{VMEM_SCOPED_LIMIT / 2**20:.0f} MiB scoped-VMEM limit")
 
     ap = _pad_to(a, bm, bk)
     bp = _pad_to(b, bk, bn)
@@ -107,8 +124,8 @@ def block_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), cp.dtype),
-        scratch_shapes=[_MS.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CP(
+        scratch_shapes=[pltpu.MemorySpace.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -9,6 +9,9 @@ hot kernel behind the ``decode_32k`` / ``long_500k`` serving shapes.
 
 Layout: queries are grouped by KV head (GQA): q (B, Hkv, G, d) where
 G = H // Hkv, so each grid step's MXU work is a fat (G, d) x (d, bs) matmul.
+The cache is head-major, k/v (B, Hkv, S, d), so one grid step's K/V block
+(1, 1, bs, d) is a dense (bs, d) tile: its last two dims are a multiple of
+the (8, 128) tiling, which Mosaic requires of a block.
 Valid cache length is per-batch in SMEM; fully-masked blocks contribute zero.
 """
 
@@ -21,11 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.compat import tpu_compiler_params, tpu_memory_space
-
-_MS = tpu_memory_space()
-_CP = tpu_compiler_params()
 
 NEG_INF = -1e30
 
@@ -41,8 +39,8 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, out_ref, m_ref, l_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)            # (G, d)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)      # (bs, d)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)      # (bs, d)
+    k = k_ref[0, 0].astype(jnp.float32)            # (bs, d)
+    v = v_ref[0, 0].astype(jnp.float32)            # (bs, d)
 
     scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     offs = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
@@ -78,20 +76,20 @@ def flash_decode_attention(
 ) -> jax.Array:
     """Single-token GQA attention against a blocked KV cache.
 
-    q: (B, H, d); k, v: (B, S, Hkv, d); length: (B,) int32 valid positions.
+    q: (B, H, d); k, v: (B, Hkv, S, d); length: (B,) int32 valid positions.
     Returns (B, H, d).  S is padded to a multiple of ``block_s`` (padded
     positions are masked by ``length``).
     """
     B, H, d = q.shape
-    S, hkv = k.shape[1], k.shape[2]
+    hkv, S = k.shape[1], k.shape[2]
     assert H % hkv == 0, (H, hkv)
     G = H // hkv
     qg = q.reshape(B, hkv, G, d)
 
     pad = (-S) % block_s
     if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     Sp = S + pad
     k_steps = Sp // block_s
     grid = (B, hkv, k_steps)
@@ -103,18 +101,18 @@ def flash_decode_attention(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, G, d), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec(memory_space=_MS.SMEM),
+            pl.BlockSpec((1, 1, block_s, d), lambda b, h, s: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, block_s, d), lambda b, h, s: (b, h, s, 0)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, G, d), lambda b, h, s: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, hkv, G, d), q.dtype),
         scratch_shapes=[
-            _MS.VMEM((G, 128), jnp.float32),  # m
-            _MS.VMEM((G, 128), jnp.float32),  # l
-            _MS.VMEM((G, d), jnp.float32),    # acc
+            pltpu.MemorySpace.VMEM((G, 128), jnp.float32),  # m
+            pltpu.MemorySpace.VMEM((G, 128), jnp.float32),  # l
+            pltpu.MemorySpace.VMEM((G, d), jnp.float32),    # acc
         ],
-        compiler_params=_CP(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,9 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On the CPU container the kernels run in ``interpret=True`` mode (kernel body
-executed in Python — the validation target per the brief); on TPU they lower
-through Mosaic.  ``auto_interpret()`` picks per-platform so model code can
-call these unconditionally.
+On a TPU the kernels lower through Mosaic.  Anywhere else the caller must
+ask for Pallas interpret mode (``interpret=True``: the kernel body runs in
+Python, which is how the CPU tests validate it); without that choice the
+wrappers raise instead of quietly running somewhere other than the chip.
 """
 
 from __future__ import annotations
@@ -16,20 +16,26 @@ from repro.kernels.flash_attention import (
 )
 
 
-def auto_interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
+def check_interpret(interpret: bool) -> bool:
+    """``interpret`` if it is allowed here: compiled kernels need a TPU."""
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"Pallas TPU kernels need a TPU, and the JAX backend is "
+            f"{jax.default_backend()!r}; pass interpret=True to run them in "
+            f"Pallas interpret mode")
+    return interpret
 
 
 def block_matmul(a, b, c, *, alpha=1.0, beta=0.0, block=(512, 512, 512),
-                 interpret=None):
+                 interpret=False):
     return _block_matmul(
         a, b, c, alpha=alpha, beta=beta, block=tuple(block),
-        interpret=auto_interpret() if interpret is None else interpret,
+        interpret=check_interpret(interpret),
     )
 
 
-def flash_decode_attention(q, k, v, length, *, block_s=512, interpret=None):
+def flash_decode_attention(q, k, v, length, *, block_s=512, interpret=False):
     return _flash_decode_attention(
         q, k, v, length, block_s=block_s,
-        interpret=auto_interpret() if interpret is None else interpret,
+        interpret=check_interpret(interpret),
     )

@@ -17,6 +17,24 @@ def gemm_ref(a, b, c=None, alpha: float = 1.0, beta: float = 0.0):
     return out.astype(dtype)
 
 
+def gemm_error_bound(a, b, c=None, alpha: float = 1.0, beta: float = 0.0,
+                     dtype=np.float32) -> np.ndarray:
+    """Elementwise bound on ``|computed - exact|`` for ``alpha*a@b + beta*c``
+    evaluated in ``dtype`` with the K-term sums taken in ANY order:
+    ``gamma_{K+2} * (|alpha| |a| @ |b| + |beta| |c|)``, where
+    ``gamma_n = n u / (1 - n u)`` and ``u`` is the unit roundoff.  Two
+    results that differ only in summation order (block geometry, backend
+    kernel choice) therefore differ by at most twice this bound."""
+    a = np.abs(np.asarray(a, np.float64))
+    b = np.abs(np.asarray(b, np.float64))
+    u = np.finfo(dtype).eps / 2
+    n = a.shape[1] + 2
+    mag = abs(alpha) * (a @ b)
+    if c is not None:
+        mag = mag + abs(beta) * np.abs(np.asarray(c, np.float64))
+    return n * u / (1 - n * u) * mag
+
+
 def decode_attention_ref(q, k, v, length=None):
     """Single-token GQA attention oracle.
 

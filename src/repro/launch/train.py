@@ -25,9 +25,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_arch
@@ -46,7 +44,8 @@ def make_local_mesh() -> Mesh:
         if n % m == 0 and m <= n:
             model = m
             break
-    return make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def main(argv=None) -> dict:

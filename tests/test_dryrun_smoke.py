@@ -18,16 +18,16 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.compat import make_mesh
 from repro.configs import get_arch, SHAPES
 from repro.distributed import make_weight_gather, tree_shardings
 from repro.models import get_model
 from repro.optim import AdamWConfig
 from repro.training import steps as tsteps
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 results = {}
 for arch in ["llama3.2-3b", "deepseek-moe-16b", "rwkv6-1.6b", "zamba2-1.2b"]:
     cfg = get_arch(arch).smoke().replace(num_heads=4, num_kv_heads=4)

@@ -22,8 +22,8 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
 from repro.distributed import tree_shardings
@@ -35,7 +35,8 @@ ndev, shape, mode, ckpt = (int(sys.argv[1]), sys.argv[2], sys.argv[3],
                            sys.argv[4])
 rows, cols = (int(x) for x in shape.split("x"))
 assert rows * cols == ndev, (shape, ndev)
-mesh = make_mesh((rows, cols), ("data", "model"))
+mesh = jax.make_mesh((rows, cols), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = get_arch("stablelm-1.6b").smoke().replace(num_heads=4, num_kv_heads=4)
 model = get_model(cfg)
 opt = AdamWConfig()

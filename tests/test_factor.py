@@ -9,8 +9,7 @@ so float64 inputs are held to f32-level tolerances.
 
 import numpy as np
 import pytest
-
-from tests._hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (compile_factor_pipeline, factor_pipeline_spec,
                         ooc_cholesky, ooc_lu, schedule_stats,

@@ -342,7 +342,10 @@ def test_device_lost_syrk_recovers_via_gemm_band():
     out, _ = run_hybrid_syrk(P, C, 1.2, 0.5, hp,
                              fault_plans={"gpu0": _first_compute_lost},
                              fault_policy=FaultPolicy(sleep=lambda s: None))
-    assert np.array_equal(out, clean)
+    # the survivor recomputes gpu0's band with its own block geometry, so
+    # the result matches up to fp32 summation order, not bitwise
+    bound = ref.gemm_error_bound(P, P.T, C, 1.2, 0.5)
+    assert np.all(np.abs(out - clean) <= 2 * bound)
 
 
 def test_surviving_devices_validation():

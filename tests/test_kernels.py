@@ -63,6 +63,28 @@ def test_block_matmul_beta_zero_ignores_c_nans(rng):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("kernel", ["block_matmul", "flash_decode_attention"])
+def test_wrappers_refuse_to_interpret_unasked(kernel):
+    """Off a TPU a kernel runs only if the caller chose interpret mode."""
+    assert jax.default_backend() != "tpu"
+    x = jnp.zeros((1, 128, 128), jnp.float32)
+    args = ((x[0], x[0], x[0]) if kernel == "block_matmul"
+            else (x[:, :8], x[:, None], x[:, None], jnp.ones((1,), jnp.int32)))
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        getattr(ops, kernel)(*args)
+
+
+def test_block_matmul_rejects_block_over_scoped_vmem(rng):
+    A = jnp.zeros((256, 256), jnp.float32)
+    with pytest.raises(ValueError, match="scoped-VMEM limit"):
+        ops.block_matmul(A, A, A, block=(2048, 2048, 256), interpret=True)
+
+
+def _seq_major(x):
+    """Kernel cache layout (B, Hkv, S, d) -> the oracle's (B, S, Hkv, d)."""
+    return jnp.swapaxes(x, 1, 2)
+
+
 @pytest.mark.parametrize("B,H,hkv,d,S,block_s", [
     (1, 8, 2, 64, 512, 128),
     (2, 16, 16, 64, 1000, 256),   # MHA, non-divisible S
@@ -71,12 +93,13 @@ def test_block_matmul_beta_zero_ignores_c_nans(rng):
 ])
 def test_flash_decode_attention(rng, B, H, hkv, d, S, block_s):
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, S, hkv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, S, hkv, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, hkv, S, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, hkv, S, d)), jnp.float32)
     lengths = jnp.asarray(rng.integers(1, S + 1, (B,)), jnp.int32)
     out = ops.flash_decode_attention(q, k, v, lengths, block_s=block_s,
                                      interpret=True)
-    expect = ref.decode_attention_ref(q, k, v, lengths)
+    expect = ref.decode_attention_ref(q, _seq_major(k), _seq_major(v),
+                                      lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)
 
@@ -84,12 +107,13 @@ def test_flash_decode_attention(rng, B, H, hkv, d, S, block_s):
 def test_flash_decode_bf16(rng):
     B, H, hkv, d, S = 2, 8, 2, 64, 512
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((B, S, hkv, d)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((B, S, hkv, d)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((B, hkv, S, d)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((B, hkv, S, d)), jnp.bfloat16)
     lengths = jnp.full((B,), S, jnp.int32)
     out = ops.flash_decode_attention(q, k, v, lengths, block_s=128,
                                      interpret=True)
-    expect = ref.decode_attention_ref(q, k, v, lengths)
+    expect = ref.decode_attention_ref(q, _seq_major(k), _seq_major(v),
+                                      lengths)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                rtol=3e-2, atol=3e-2)
@@ -99,13 +123,14 @@ def test_flash_decode_fully_masked_block(rng):
     """Blocks entirely beyond `length` must contribute exactly nothing."""
     B, H, hkv, d, S = 1, 4, 4, 64, 1024
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, S, hkv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, S, hkv, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, hkv, S, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, hkv, S, d)), jnp.float32)
     short = jnp.asarray([100], jnp.int32)
     out = ops.flash_decode_attention(q, k, v, short, block_s=128,
                                      interpret=True)
     # identical to attention over the truncated cache
-    expect = ref.decode_attention_ref(q, k[:, :100], v[:, :100],
+    expect = ref.decode_attention_ref(q, _seq_major(k[:, :, :100]),
+                                      _seq_major(v[:, :, :100]),
                                       jnp.asarray([100], jnp.int32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)

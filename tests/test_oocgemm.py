@@ -4,10 +4,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
-from tests._hypothesis_shim import given, settings, st
-
-from repro.core import is_in_core, ooc_gemm, ooc_syrk
+from repro.core import VmemOocRuntime, is_in_core, ooc_gemm, ooc_syrk
 from repro.core.api import (hclDeviceFactory, hclGetMemSize,
                             hclMatrixPartitioner, hclRuntimeFactory)
 from repro.core.ooc_attention import ooc_attention
@@ -55,7 +54,8 @@ def test_ooc_gemm_vmem_backend(rng):
     A, B, C = _problem(rng, 256, 256, 256)
     budget = A.nbytes  # force OOC
     out = ooc_gemm(jnp.asarray(A), jnp.asarray(B), jnp.asarray(C),
-                   1.0, 1.0, budget_bytes=budget, backend="vmem")
+                   1.0, 1.0, budget_bytes=budget, backend="vmem",
+                   runtime=VmemOocRuntime(interpret=True))
     expect = A.astype(np.float64) @ B + C
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-4, atol=1e-4)
 
@@ -142,8 +142,9 @@ def test_ooc_syrk_matches_oracle(rng, backend):
     P = rng.standard_normal((n, k)).astype(np.float32)
     C = rng.standard_normal((n, n)).astype(np.float32)
     budget = (2 * P.nbytes + C.nbytes) // 4  # force out-of-core
-    out = ooc_syrk(P, C, -2.0, 0.5, budget_bytes=budget,
-                   backend=backend, validate=(backend == "host"))
+    rt = VmemOocRuntime(interpret=True) if backend == "vmem" else None
+    out = ooc_syrk(P, C, -2.0, 0.5, budget_bytes=budget, backend=backend,
+                   validate=(backend == "host"), runtime=rt)
     expect = np.asarray(ref.gemm_ref(
         jnp.asarray(P), jnp.asarray(P).T, jnp.asarray(C),
         alpha=-2.0, beta=0.5))

@@ -2,7 +2,7 @@
 
 import numpy as np
 import pytest
-from tests._hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.partitioner import (
     GemmPartition,

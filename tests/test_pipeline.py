@@ -1,8 +1,7 @@
 """Schedule correctness (the paper's event program) + simulator properties."""
 
 import pytest
-
-from tests._hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     OpKind,

@@ -1,6 +1,6 @@
 """Property-based conformance suite (ISSUE 4).
 
-Two families, driven through the hypothesis shim:
+Two families, driven by hypothesis:
 
   * every randomly-drawn partition / pipeline-spec / config combination
     compiles to a schedule that passes ``validate_schedule`` (the event
@@ -15,8 +15,7 @@ Two families, driven through the hypothesis shim:
 
 import numpy as np
 import pytest
-
-from tests._hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     OpKind,

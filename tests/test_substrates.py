@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from tests._hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import CheckpointManager
 from repro.data import MemmapSource, Prefetcher, SyntheticSource
