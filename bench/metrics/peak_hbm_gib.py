@@ -1,0 +1,5 @@
+"""``peak_bytes_in_use`` of the fullest chip after the window, in GiB."""
+
+
+def read(run):
+    return run.memory_peak_bytes / 2**30 if run.memory_peak_bytes else None
