@@ -1,13 +1,9 @@
 """Export an OOC pipeline timeline as chrome://tracing JSON.
 
-Three span sources, one trace format (``repro.core.trace``):
+Engine-model spans, one trace format (``repro.core.trace``):
 
-  * ``--mode sim``  — engine-model spans from ``simulate()`` under a named
-    hardware model: what the schedule *predicts* (the C3/C5 overlap story).
-  * ``--mode exec`` — wall-clock spans from ``ScheduleExecutor`` running the
-    schedule on random data with ``record_spans=True``: what this machine
-    *does* (note: recording synchronizes per op, so overlap collapses — use
-    it to inspect op ordering and real per-op costs, not speedups).
+  * ``--mode sim``  — spans from ``simulate()`` under a named hardware
+    model: what the schedule *predicts* (the C3/C5 overlap story).
   * ``--mode hybrid`` — engine-model spans of a GEMM co-scheduled across
     the canned gpu+phi profile pair: one trace *process* (lane-group, pid =
     device index) per device, so the balanced concurrent timelines sit side
@@ -23,6 +19,11 @@ elided-transfer effect is visible by diffing two exports.
 
 Open the output at chrome://tracing or https://ui.perfetto.dev.
 
+What a chip *does* is in a profiler trace instead: ``bench/run.py --trace
+1`` records one around the benchmark's window, where the engine's
+``ooc.*`` spans (``repro.obs.annotate``) sit on the host plane beside the
+device's operations, on one clock.
+
 Example:
     PYTHONPATH=src python scripts/export_trace.py --mode sim \
         --M 2048 --N 2048 --K 1024 --budget-mb 16 --hw gpu -o trace.json
@@ -34,10 +35,8 @@ import argparse
 import json
 import sys
 
-import numpy as np
-
-from repro.core import (EVICT_POLICIES, TRAVERSALS, HostOocRuntime, OpKind,
-                        ScheduleExecutor, build_gemm_schedule, chrome_trace,
+from repro.core import (EVICT_POLICIES, TRAVERSALS, OpKind,
+                        build_gemm_schedule, chrome_trace,
                         compile_factor_pipeline, factor_pipeline_spec,
                         gpu_like, phi_like, plan_gemm_partition, simulate,
                         tpu_v5e_ici, tpu_v5e_vmem)
@@ -173,7 +172,7 @@ def _factor_mode(args) -> None:
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--mode", choices=("sim", "exec", "hybrid", "factor"),
+    ap.add_argument("--mode", choices=("sim", "hybrid", "factor"),
                     default="sim")
     ap.add_argument("--M", type=int, default=2048)
     ap.add_argument("--N", type=int, default=2048)
@@ -182,9 +181,9 @@ def main() -> None:
     ap.add_argument("--nstreams", type=int, default=2)
     ap.add_argument("--nbuf", type=int, default=2)
     ap.add_argument("--traversal", choices=TRAVERSALS, default="col",
-                    help="block-grid step order (sim/exec modes)")
+                    help="block-grid step order (sim mode)")
     ap.add_argument("--evict", choices=EVICT_POLICIES, default="lru",
-                    help="block-cache eviction policy (sim/exec/factor)")
+                    help="block-cache eviction policy (sim/factor)")
     ap.add_argument("--kind", choices=("cholesky", "lu"), default="cholesky",
                     help="factorization kind for --mode factor")
     ap.add_argument("--n", type=int, default=2048,
@@ -223,28 +222,13 @@ def main() -> None:
     name = (f"gemm {args.M}x{args.N}x{args.K} h{part.h}xw{part.w} "
             f"s{args.nstreams}b{args.nbuf} {args.traversal}/{args.evict}")
 
-    analysis = None
-    if args.mode == "sim":
-        hw = HW[args.hw](args.nstreams)
-        res = simulate(sched, hw)
-        spans = res.op_spans
-        analysis = TraceAnalysis.from_sim(sched, res, hw=hw).digest()
-        log(f"{name}: {len(sched.ops)} ops, "
-            f"simulated makespan {res.makespan*1e3:.2f} ms on {args.hw}")
-    else:
-        rng = np.random.default_rng(0)
-        A = rng.standard_normal((args.M, args.K)).astype(np.float32)
-        B = rng.standard_normal((args.K, args.N)).astype(np.float32)
-        C = np.zeros((args.M, args.N), dtype=np.float32)
-        ex = ScheduleExecutor(record_spans=True)
-        HostOocRuntime(executor=ex).gemm(A, B, C, 1.0, 0.0, part,
-                                         schedule=sched)
-        spans = ex.last_spans
-        total = max(e for _, _, _, e in spans)
-        analysis = TraceAnalysis.from_spans(sched, spans).digest()
-        log(f"{name}: {len(spans)} ops executed in {total*1e3:.1f} ms wall")
+    hw = HW[args.hw](args.nstreams)
+    res = simulate(sched, hw)
+    analysis = TraceAnalysis.from_sim(sched, res, hw=hw).digest()
+    log(f"{name}: {len(sched.ops)} ops, "
+        f"simulated makespan {res.makespan*1e3:.2f} ms on {args.hw}")
 
-    doc = chrome_trace(spans, process_name=name, reuse=sched.reuse)
+    doc = chrome_trace(res.op_spans, process_name=name, reuse=sched.reuse)
     doc["otherData"] = {"h2d_bytes": sched.total_bytes(OpKind.H2D),
                         "d2h_bytes": sched.total_bytes(OpKind.D2H),
                         "analysis": analysis}

@@ -180,78 +180,86 @@ def ooc_gemm(
     injected oom walks the degradation ladder (halve nbuf, then halve the
     budget — tuned runs re-search at the reduced budget) and re-executes
     clean.
+
+    The whole call is the ``ooc.gemm`` span (:meth:`Observability.span`),
+    on every branch.
     """
-    if tune not in (None, "auto"):
-        raise ValueError(f"unknown tune mode {tune!r}; expected None/'auto'")
-    if faults is not None and (devices is not None or backend != "host"):
-        raise ValueError("fault injection is supported on the host "
-                         "pipeline backend only (hybrid paths take "
-                         "fault_plans on run_hybrid_*)")
-    if devices is not None:
-        from repro.hybrid import plan_hybrid_gemm, run_hybrid_gemm
+    with get_observability().span("ooc.gemm", cat="entry"):
+        if tune not in (None, "auto"):
+            raise ValueError(
+                f"unknown tune mode {tune!r}; expected None/'auto'")
+        if faults is not None and (devices is not None or backend != "host"):
+            raise ValueError("fault injection is supported on the host "
+                             "pipeline backend only (hybrid paths take "
+                             "fault_plans on run_hybrid_*)")
+        if devices is not None:
+            from repro.hybrid import plan_hybrid_gemm, run_hybrid_gemm
 
-        A = np.asarray(A)
-        B = np.asarray(B)
-        hplan = plan_hybrid_gemm(
-            A.shape[0], B.shape[1], A.shape[1], devices,
-            dtype=np.dtype(A.dtype).name, **_hybrid_kwargs(tolerance))
-        out, _ = run_hybrid_gemm(A, B, C, alpha, beta, hplan,
-                                 validate=validate)
-        return out
-    if backend == "mesh":
-        # operands go from where they are straight to their shards, never
-        # whole onto the default device first
-        if C is None:
-            C = np.zeros((A.shape[0], B.shape[1]), dtype=A.dtype)
-            beta = 0.0
-        rt = runtime or MeshOocRuntime(mesh)
-        return rt.gemm(A, B, C, alpha, beta, None)
-    A = np.asarray(A) if backend == "host" else jnp.asarray(A)
-    B = np.asarray(B) if backend == "host" else jnp.asarray(B)
-    M, K = A.shape
-    K2, N = B.shape
-    if K != K2:
-        raise ValueError(f"inner dims mismatch: {A.shape} @ {B.shape}")
-    if C is None:
-        C = np.zeros((M, N), dtype=A.dtype) if backend == "host" \
-            else jnp.zeros((M, N), dtype=A.dtype)
-        beta = 0.0
-    bpe = np.dtype(A.dtype).itemsize
-
-    if is_in_core(M, N, K, budget_bytes, bpe):
-        # In-core fast path: one resident DGEMM (claim C2 transition point).
-        out = _block_dgemm(jnp.asarray(A), jnp.asarray(B), jnp.asarray(C),
-                           jnp.float32(alpha), jnp.float32(beta))
-        return np.asarray(out) if backend == "host" else out
-
-    tuned = None
-    if tune == "auto" and backend == "host":
-        tuned = _tuned_gemm_plan(tuner, "gemm", M, N, K, budget_bytes,
-                                 A.dtype)
-        part, nstreams, nbuf = (tuned.gemm_partition(), tuned.nstreams,
-                                tuned.nbuf)
-        traversal, evict = tuned.traversal, tuned.evict
-    else:
-        part = plan_gemm_partition(M, N, K, budget_bytes, bpe)
-    if backend == "host":
-        sched = plib.build_gemm_schedule(part, nstreams=nstreams, nbuf=nbuf,
-                                         traversal=traversal, evict=evict)
-        if validate:
-            validate_schedule(sched)
-        rt = runtime or HostOocRuntime()
-        if faults is None:
-            out = rt.gemm(A, B, C, alpha, beta, part, schedule=sched)
-            _record_host_drift(tuned, rt, sched)
+            A = np.asarray(A)
+            B = np.asarray(B)
+            hplan = plan_hybrid_gemm(
+                A.shape[0], B.shape[1], A.shape[1], devices,
+                dtype=np.dtype(A.dtype).name, **_hybrid_kwargs(tolerance))
+            out, _ = run_hybrid_gemm(A, B, C, alpha, beta, hplan,
+                                     validate=validate)
             return out
-        return _host_gemm_resilient(
-            rt, A, B, C, alpha, beta, part, sched, faults=faults,
-            policy=fault_policy, tuned=tuned, tune=tune, tuner=tuner,
-            nstreams=nstreams, nbuf=nbuf, traversal=traversal, evict=evict,
-            budget_bytes=budget_bytes, bpe=bpe)
-    if backend == "vmem":
-        rt = runtime or VmemOocRuntime()
-        return rt.gemm(A, B, C, alpha, beta, part)
-    raise ValueError(f"unknown backend {backend!r}")
+        if backend == "mesh":
+            # operands go from where they are straight to their shards,
+            # never whole onto the default device first
+            if C is None:
+                C = np.zeros((A.shape[0], B.shape[1]), dtype=A.dtype)
+                beta = 0.0
+            rt = runtime or MeshOocRuntime(mesh)
+            return rt.gemm(A, B, C, alpha, beta, None)
+        A = np.asarray(A) if backend == "host" else jnp.asarray(A)
+        B = np.asarray(B) if backend == "host" else jnp.asarray(B)
+        M, K = A.shape
+        K2, N = B.shape
+        if K != K2:
+            raise ValueError(f"inner dims mismatch: {A.shape} @ {B.shape}")
+        if C is None:
+            C = np.zeros((M, N), dtype=A.dtype) if backend == "host" \
+                else jnp.zeros((M, N), dtype=A.dtype)
+            beta = 0.0
+        bpe = np.dtype(A.dtype).itemsize
+
+        if is_in_core(M, N, K, budget_bytes, bpe):
+            # In-core fast path: one resident DGEMM (claim C2 transition
+            # point).
+            out = _block_dgemm(jnp.asarray(A), jnp.asarray(B),
+                               jnp.asarray(C), jnp.float32(alpha),
+                               jnp.float32(beta))
+            return np.asarray(out) if backend == "host" else out
+
+        tuned = None
+        if tune == "auto" and backend == "host":
+            tuned = _tuned_gemm_plan(tuner, "gemm", M, N, K, budget_bytes,
+                                     A.dtype)
+            part, nstreams, nbuf = (tuned.gemm_partition(), tuned.nstreams,
+                                    tuned.nbuf)
+            traversal, evict = tuned.traversal, tuned.evict
+        else:
+            part = plan_gemm_partition(M, N, K, budget_bytes, bpe)
+        if backend == "host":
+            sched = plib.build_gemm_schedule(part, nstreams=nstreams,
+                                             nbuf=nbuf, traversal=traversal,
+                                             evict=evict)
+            if validate:
+                validate_schedule(sched)
+            rt = runtime or HostOocRuntime()
+            if faults is None:
+                out = rt.gemm(A, B, C, alpha, beta, part, schedule=sched)
+                _record_host_drift(tuned, rt, sched)
+                return out
+            return _host_gemm_resilient(
+                rt, A, B, C, alpha, beta, part, sched, faults=faults,
+                policy=fault_policy, tuned=tuned, tune=tune, tuner=tuner,
+                nstreams=nstreams, nbuf=nbuf, traversal=traversal,
+                evict=evict, budget_bytes=budget_bytes, bpe=bpe)
+        if backend == "vmem":
+            rt = runtime or VmemOocRuntime()
+            return rt.gemm(A, B, C, alpha, beta, part)
+        raise ValueError(f"unknown backend {backend!r}")
 
 
 def ooc_syrk(

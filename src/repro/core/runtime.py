@@ -41,7 +41,7 @@ from repro.core.exec_plan import ExecutablePlan, compile_executable
 from repro.core.partitioner import GemmPartition, plan_gemm_partition
 from repro.core.streams import (BlockRef, Device, Op, OpKind, Schedule,
                                 ScheduleError, SliceRef)
-from repro.obs import get_observability
+from repro.obs import annotate, get_observability
 
 
 class OocRuntime:
@@ -177,6 +177,32 @@ def _put_block(host_block) -> jax.Array:
     return jnp.asarray(host_block).block_until_ready()
 
 
+def _load(st: ExecState, ref: SliceRef, nbytes: int) -> jax.Array:
+    """An H2D op's transfer: slice the host operand and put it on the
+    device, under the ``ooc.exec.h2d`` profiler span."""
+    with annotate("ooc.exec.h2d", bytes=nbytes):
+        return _put_block(_take(st.host(ref.operand), ref))
+
+
+def _land(blk, ref: SliceRef, outputs: Dict[str, np.ndarray]) -> None:
+    """Write a device block back into its slice of a host output:
+    ``ooc.exec.d2h`` waits for the block and copies it to the host,
+    ``ooc.exec.store`` stores it into the output."""
+    with annotate("ooc.exec.d2h"):
+        arr = np.asarray(blk)
+    dest = outputs[ref.operand]
+    if ref.transpose:
+        arr = arr.T
+    rs, rn = ref.rows if ref.rows is not None else (0, dest.shape[0])
+    with annotate("ooc.exec.store"):
+        if dest.ndim > 1:
+            cs, cn = ref.cols if ref.cols is not None \
+                else (0, dest.shape[1])
+            dest[rs:rs + rn, cs:cs + cn] = arr
+        else:
+            dest[rs:rs + rn] = arr
+
+
 def _spans_overlap(a: SliceRef, b: SliceRef, shape) -> bool:
     def hit(sa, sb, extent):
         lo_a, n_a = sa if sa is not None else (0, extent)
@@ -248,6 +274,15 @@ class ScheduleExecutor:
     spans is therefore reliable only through the event edges, not through
     raw timestamp comparison — which is exactly the tolerance
     ``TraceAnalysis.from_spans`` applies.
+
+    Every run also writes spans onto the profiler's timeline
+    (:func:`repro.obs.annotate`), in both modes and with no
+    synchronization of their own: ``ooc.exec.run`` around the whole run,
+    and per transfer ``ooc.exec.h2d`` (with its ``bytes``),
+    ``ooc.exec.d2h`` (the wait for a written-back block and its copy to
+    the host) and ``ooc.exec.store`` (its store into the host output).  A
+    ``jax.profiler`` trace then shows the host's time beside the device's
+    operations, on one clock.
 
     ``last_h2d_bytes``/``last_d2h_bytes`` count the bytes of the transfer
     ops the executor actually performed in the most recent :meth:`run` —
@@ -326,6 +361,13 @@ class ScheduleExecutor:
             ctx: Optional[Dict[str, Any]] = None,
             faults=None,
             policy=None) -> ExecState:
+        """Execute ``sched``, writing back into ``outputs``, under the
+        ``ooc.exec.run`` profiler span (plan compile included)."""
+        with annotate("ooc.exec.run"):
+            return self._run(sched, operands, outputs, ctx, faults, policy)
+
+    def _run(self, sched, operands, outputs, ctx, faults,
+             policy) -> ExecState:
         st = ExecState(bufs={}, operands=operands, outputs=outputs,
                        ctx=ctx or {}, scratch={})
         # compile (or fetch the cached) ExecutablePlan: pre-resolved
@@ -360,18 +402,7 @@ class ScheduleExecutor:
             # block or the host store raises, the entry must stay in flight
             # so a retry re-lands it — popping first made later finalize
             # handlers silently observe stale host state
-            blk, ref = pending[key]
-            arr = np.asarray(blk)
-            dest = st.outputs[ref.operand]
-            if ref.transpose:
-                arr = arr.T
-            rs, rn = ref.rows if ref.rows is not None else (0, dest.shape[0])
-            if dest.ndim > 1:
-                cs, cn = ref.cols if ref.cols is not None \
-                    else (0, dest.shape[1])
-                dest[rs:rs + rn, cs:cs + cn] = arr
-            else:
-                dest[rs:rs + rn] = arr
+            _land(*pending[key], st.outputs)
             del pending[key]
 
         # ---- fault injection state (armed only when a plan is passed) ----
@@ -433,7 +464,7 @@ class ScheduleExecutor:
                 for k in [k for k, (_, pref) in pending.items()
                           if _spans_overlap(ref, pref, src_shape)]:
                     flush_retrying(k)
-            st.bufs[key] = _put_block(_take(st.host(ref.operand), ref))
+            st.bufs[key] = _load(st, ref, op.bytes)
             if fi is not None:   # fresh load = host-consistent snapshot
                 clean[key] = st.bufs[key]
                 chains[key] = []
@@ -661,29 +692,12 @@ class ScheduleExecutor:
             fn = resolved[i]
             return fn if fn is not None else self._handler(ref)
 
-        def land(blk: Any, ref: SliceRef) -> None:
-            # synchronous D2H: np.asarray blocks this worker (the "copy
-            # engine") until the device value is ready, then stores it —
-            # the concurrent analogue of the serial pending-flush
-            arr = np.asarray(blk)
-            dest = st.outputs[ref.operand]
-            if ref.transpose:
-                arr = arr.T
-            rs, rn = ref.rows if ref.rows is not None else (0, dest.shape[0])
-            if dest.ndim > 1:
-                cs, cn = ref.cols if ref.cols is not None \
-                    else (0, dest.shape[1])
-                dest[rs:rs + rn, cs:cs + cn] = arr
-            else:
-                dest[rs:rs + rn] = arr
-
         def dispatch(e: int, i: int, op: Op) -> None:
             ref = op.payload
             kind = plan.kinds[i]
             if kind == _xplan.KIND_H2D:
                 eng_h2d[e] += op.bytes
-                st.bufs[op.buffers_written[0]] = _put_block(
-                    _take(st.host(ref.operand), ref))
+                st.bufs[op.buffers_written[0]] = _load(st, ref, op.bytes)
             elif kind == _xplan.KIND_COMPUTE:
                 handler_at(i, ref)(st, op, ref)
             else:  # D2H
@@ -691,7 +705,10 @@ class ScheduleExecutor:
                 if isinstance(ref, BlockRef):   # finalize handler
                     handler_at(i, ref)(st, op, ref)
                 else:
-                    land(st.bufs[op.buffers_read[0]], ref)
+                    # synchronous D2H: blocks this worker (the "copy
+                    # engine"), not the pipeline — the concurrent analogue
+                    # of the serial pending-flush
+                    _land(st.bufs[op.buffers_read[0]], ref, st.outputs)
 
         def worker(e: int) -> None:
             spans = eng_spans[e]
@@ -892,7 +909,8 @@ class HostOocRuntime(OocRuntime):
         sched = schedule or plib.build_gemm_schedule(
             part, nstreams=nstreams, nbuf=nbuf
         )
-        out = np.array(C, copy=True)
+        with get_observability().span("ooc.entry.copy_c", cat="entry"):
+            out = np.array(C, copy=True)
         self.executor.run(
             sched,
             operands={"A": np.asarray(A), "B": np.asarray(B)},
@@ -911,7 +929,8 @@ class HostOocRuntime(OocRuntime):
         sched = schedule or plib.build_syrk_schedule(
             part, nstreams=nstreams, nbuf=nbuf
         )
-        out = np.array(C, copy=True)
+        with get_observability().span("ooc.entry.copy_c", cat="entry"):
+            out = np.array(C, copy=True)
         self.executor.run(
             sched,
             operands={"P": np.asarray(P)},

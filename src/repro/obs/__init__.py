@@ -11,10 +11,18 @@ pillars every other subsystem reports into:
   * :class:`~repro.obs.drift.DriftMonitor` — predicted-vs-measured rolling
     drift per (kernel, tier, fingerprint): the calibration-staleness signal.
 
-Everything starts **disabled** and instrumented hot paths guard on
+Spans also go onto the profiler's own timeline: :func:`annotate` is a
+``jax.profiler.TraceAnnotation``, on the clock of the device's operations
+in a ``jax.profiler`` trace, and :meth:`Observability.span` enters one
+whether or not a tracer is active.  The engine's ``ooc.*`` spans (the
+entry, its result copy, the executor's run and each transfer) are read
+from there by the chip benchmark.
+
+Everything else starts **disabled** and instrumented hot paths guard on
 ``obs.metrics.enabled`` / ``obs.tracer is None``, publishing only per-run
 aggregates — so the disabled cost is a few branches per kernel call
-(guarded <2 % in ``benchmarks/bench_overhead.py``).
+(guarded <2 % in ``benchmarks/bench_overhead.py``), and a profiler span
+with no profiler session running costs about a microsecond.
 
 Usage (also via the :func:`repro.core.api.hclObservability` facade)::
 
@@ -35,6 +43,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.drift import DriftMonitor, DriftRecord, key_str
 from repro.obs.metrics import (Counter, Gauge, Histogram, Metric,
                                MetricRegistry)
@@ -43,8 +53,8 @@ from repro.obs.spans import FlatSpan, Tracer, TraceSpan
 __all__ = [
     "Counter", "DriftMonitor", "DriftRecord", "FlatSpan", "Gauge",
     "Histogram", "Metric", "MetricRegistry", "Observability", "TraceAnalysis",
-    "TraceSpan", "Tracer", "WhatIfReport", "get_observability", "key_str",
-    "whatif",
+    "TraceSpan", "Tracer", "WhatIfReport", "annotate", "get_observability",
+    "key_str", "whatif",
 ]
 
 # Attribution lives in submodules that import repro.core (the simulator);
@@ -66,22 +76,38 @@ def __getattr__(name: str):
     return getattr(importlib.import_module(target[0]), target[1])
 
 
-class _NullSpan:
-    """No-tracer stand-in so call sites can unconditionally ``with``."""
+def annotate(name: str, **args):
+    """A span on the profiler's timeline: the host plane of a
+    ``jax.profiler`` trace, the clock the device's operations are on.
+    ``args`` travel with it as metadata.  Entered with no profiler session
+    running it costs about a microsecond and records nothing."""
+    return TraceAnnotation(name, **args)
 
-    __slots__ = ()
 
-    def __enter__(self):
+class _Span:
+    """:meth:`Observability.span`'s context: a profiler annotation, and a
+    :class:`Tracer` span while a tracer is active."""
+
+    __slots__ = ("_mark", "_handle")
+
+    def __init__(self, mark, handle):
+        self._mark = mark
+        self._handle = handle
+
+    def __enter__(self) -> "_Span":
+        self._mark.__enter__()
         return self
 
-    def __exit__(self, *exc):
-        return None
+    def __exit__(self, *exc) -> None:
+        if self._handle is not None:
+            self._handle.__exit__(*exc)
+        self._mark.__exit__(*exc)
 
     def annotate(self, **kw) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
+        """Attach key/values to the tracer span (the profiler's metadata is
+        fixed when the span opens)."""
+        if self._handle is not None:
+            self._handle.annotate(**kw)
 
 
 class Observability:
@@ -133,11 +159,13 @@ class Observability:
             tr, self.tracer = self.tracer, None
             return tr
 
-    def span(self, name: str, cat: str = "phase", **args):
-        """A tracer span when tracing is active, else a free no-op."""
+    def span(self, name: str, cat: str = "phase", **args) -> _Span:
+        """A span on the profiler's timeline (:func:`annotate`), recorded
+        into the tracer too while one is active."""
         tr = self.tracer
-        return tr.span(name, cat=cat, **args) if tr is not None \
-            else _NULL_SPAN
+        return _Span(annotate(name, **args),
+                     tr.span(name, cat=cat, **args) if tr is not None
+                     else None)
 
     def instant(self, name: str, cat: str = "fault", **args) -> None:
         """A zero-duration trace marker when tracing is active (fault
