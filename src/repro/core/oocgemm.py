@@ -227,7 +227,7 @@ def ooc_gemm(
             # In-core fast path: one resident DGEMM (claim C2 transition
             # point).
             out = _block_dgemm(jnp.asarray(A), jnp.asarray(B),
-                               jnp.asarray(C), jnp.float32(alpha),
+                               jnp.array(C), jnp.float32(alpha),
                                jnp.float32(beta))
             return np.asarray(out) if backend == "host" else out
 
@@ -331,7 +331,7 @@ def ooc_syrk(
     bpe = np.dtype(P.dtype).itemsize
 
     if is_in_core(n, n, K, budget_bytes, bpe):
-        out = _block_dgemm(jnp.asarray(P), jnp.asarray(P).T, jnp.asarray(C),
+        out = _block_dgemm(jnp.asarray(P), jnp.asarray(P).T, jnp.array(C),
                            jnp.float32(alpha), jnp.float32(beta))
         return np.asarray(out) if backend == "host" else out
 

@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple, Type
@@ -99,9 +100,13 @@ def register_runtime(name: str) -> Callable[[Type[OocRuntime]],
     return deco
 
 
-@functools.partial(jax.jit, static_argnames=("transpose",))
+@functools.partial(jax.jit, static_argnames=("transpose",),
+                   donate_argnums=(2,))
 def _block_dgemm(a, b, c, alpha, beta, transpose: bool = False):
-    """In-core DGEMM on resident blocks (the vendor-kernel slot)."""
+    """In-core DGEMM on resident blocks (the vendor-kernel slot).  ``c`` is
+    donated: the result takes its device memory, so a product in flight
+    holds one C block, not an input and an output.  Callers pass a ``c``
+    that nothing reads afterwards."""
     acc = jnp.dot(a, b, preferred_element_type=jnp.float32)
     return (alpha * acc + beta * c).astype(c.dtype)
 
@@ -425,7 +430,8 @@ class ScheduleExecutor:
             # per-buffer recovery state: the value at the last
             # host-consistent point (H2D load / write-back dispatch) and
             # the compute chain applied since — buffer reassignment makes
-            # these O(1) reference snapshots, not copies
+            # these O(1) reference snapshots, except the load's, which the
+            # block product would donate
             clean: Dict[Tuple[str, Hashable], Any] = {}
             chains: Dict[Tuple[str, Hashable], List] = {}
 
@@ -466,7 +472,7 @@ class ScheduleExecutor:
                     flush_retrying(k)
             st.bufs[key] = _load(st, ref, op.bytes)
             if fi is not None:   # fresh load = host-consistent snapshot
-                clean[key] = st.bufs[key]
+                clean[key] = jnp.copy(st.bufs[key])
                 chains[key] = []
 
         def exec_compute(i, op, ref) -> None:
@@ -563,7 +569,7 @@ class ScheduleExecutor:
                         f"op {i} ({op.tag}): compute fault "
                         + ("retries exhausted" if replayable
                            else "not replayable"))
-                st.bufs[key] = clean[key]
+                st.bufs[key] = jnp.copy(clean[key])
                 for cop, cref, creads in chains[key]:
                     saved = {}
                     for rk, rv in creads.items():
@@ -887,6 +893,17 @@ def _lu_writeback_handler(st: ExecState, op: Op, ref: BlockRef) -> None:
     A[k0:, k0:k1] = buf.astype(A.dtype)
 
 
+def _sole_refcount() -> int:
+    """What ``sys.getrefcount`` reads of an array that one local alone
+    holds.  Measured, not assumed: the interpreter's own references to a
+    call's argument differ between versions (3.14 borrows a local's)."""
+    held = np.empty(0)
+    return sys.getrefcount(held)
+
+
+_SOLE_REFCOUNT = _sole_refcount()
+
+
 @register_runtime("HBM")
 class HostOocRuntime(OocRuntime):
     """Host-driven block streaming: builds (or accepts) a pipeline schedule
@@ -895,12 +912,62 @@ class HostOocRuntime(OocRuntime):
     transfer of block ``idx+1`` with the DGEMM of block ``idx`` exactly as
     the event program orders them; on CPU the schedule executes with
     identical semantics (ordering + results), which is what tests assert.
+
+    The runtime keeps the last result it returned and, once the caller has
+    dropped it, writes the next result of the same shape and dtype into it
+    (:meth:`_result`).  :meth:`release` drops it.
     """
 
     def __init__(self, device: Optional[Device] = None,
                  executor: Optional[ScheduleExecutor] = None):
         self.device = device or Device("HBM", 0, 16 * 2**30)
         self.executor = executor or ScheduleExecutor()
+        self._idle: Optional[np.ndarray] = None
+
+    def release(self) -> None:
+        """Drop the last result this runtime returned.  The runtime keeps
+        it to write the next result into; until the next call or this one,
+        a result the caller dropped stays mapped."""
+        self._idle = None
+
+    def _result(self, C: np.ndarray, *operands: np.ndarray) -> np.ndarray:
+        """The array a call returns, holding a copy of ``C``: the last
+        result this runtime returned, where nothing else refers to it (the
+        refcount rule of numpy's ``ndarray.resize``), it has ``C``'s shape
+        and dtype and shares no memory with the call's arrays; else a fresh
+        one, made after the old one is dropped.  Writing into pages already
+        mapped spares the kernel handing over and zeroing fresh ones.  The
+        ``ooc.entry.copy_c`` span covers getting the result and the copy;
+        within it ``ooc.entry.reuse_result`` or ``ooc.entry.alloc_result``
+        covers getting the result and names the choice."""
+        idle, self._idle = self._idle, None
+        reuse = (idle is not None
+                 and sys.getrefcount(idle) == _SOLE_REFCOUNT
+                 and idle.shape == C.shape and idle.dtype == C.dtype
+                 and not any(np.may_share_memory(idle, x)
+                             for x in (C, *operands)))
+        if not reuse:
+            idle = None            # dropped before a fresh one is made
+        with get_observability().span("ooc.entry.copy_c", cat="entry"):
+            if reuse:
+                with annotate("ooc.entry.reuse_result"):
+                    out = idle
+            else:
+                with annotate("ooc.entry.alloc_result"):
+                    out = np.empty_like(C, subok=False)
+            np.copyto(out, C)
+        return out
+
+    def _run(self, sched: Schedule, operands: Dict[str, np.ndarray], C,
+             alpha, beta, faults, policy) -> np.ndarray:
+        """Run ``sched`` on ``operands`` with the result in a copy of ``C``;
+        the result is kept for the next call only once it is returned."""
+        out = self._result(np.asarray(C), *operands.values())
+        self.executor.run(sched, operands=operands, outputs={"C": out},
+                          ctx={"alpha": alpha, "beta": beta},
+                          faults=faults, policy=policy)
+        self._idle = out
+        return out
 
     def gemm(self, A, B, C, alpha, beta, part: GemmPartition,
              nstreams: int = 2, nbuf: int = 2,
@@ -909,16 +976,8 @@ class HostOocRuntime(OocRuntime):
         sched = schedule or plib.build_gemm_schedule(
             part, nstreams=nstreams, nbuf=nbuf
         )
-        with get_observability().span("ooc.entry.copy_c", cat="entry"):
-            out = np.array(C, copy=True)
-        self.executor.run(
-            sched,
-            operands={"A": np.asarray(A), "B": np.asarray(B)},
-            outputs={"C": out},
-            ctx={"alpha": alpha, "beta": beta},
-            faults=faults, policy=policy,
-        )
-        return out
+        return self._run(sched, {"A": np.asarray(A), "B": np.asarray(B)},
+                         C, alpha, beta, faults, policy)
 
     def syrk(self, P, C, alpha, beta, part: GemmPartition,
              nstreams: int = 2, nbuf: int = 2,
@@ -929,16 +988,8 @@ class HostOocRuntime(OocRuntime):
         sched = schedule or plib.build_syrk_schedule(
             part, nstreams=nstreams, nbuf=nbuf
         )
-        with get_observability().span("ooc.entry.copy_c", cat="entry"):
-            out = np.array(C, copy=True)
-        self.executor.run(
-            sched,
-            operands={"P": np.asarray(P)},
-            outputs={"C": out},
-            ctx={"alpha": alpha, "beta": beta},
-            faults=faults, policy=policy,
-        )
-        return out
+        return self._run(sched, {"P": np.asarray(P)}, C, alpha, beta,
+                         faults, policy)
 
 
 @register_runtime("VMEM")

@@ -60,6 +60,38 @@ def test_ooc_gemm_vmem_backend(rng):
     np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("kernel", ["gemm", "syrk"])
+def test_in_core_call_leaves_a_device_c_intact(rng, kernel):
+    """The block product donates its accumulator; the in-core branch hands
+    it a copy, so a caller's C on the device is still there afterwards."""
+    A, B, C = _problem(rng, 64, 64, 32)
+    if kernel == "syrk":
+        C = C @ C.T
+        c = jnp.asarray(C)
+        out = ooc_syrk(A, c, 1.0, 0.5, budget_bytes=1 << 30, backend="vmem")
+        expect = A.astype(np.float64) @ A.T + 0.5 * C
+    else:
+        c = jnp.asarray(C)
+        out = ooc_gemm(A, B, c, 1.0, 0.5, budget_bytes=1 << 30,
+                       backend="vmem")
+        expect = A.astype(np.float64) @ B + 0.5 * C
+    assert not c.is_deleted()
+    np.testing.assert_array_equal(np.asarray(c), C)
+    np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-4,
+                               atol=1e-4)
+
+
+def test_block_product_takes_its_accumulators_memory():
+    # a product in flight holds one C block: the input C is donated to
+    # the result, which the executor's handler stores in its place
+    from repro.core.runtime import _block_dgemm
+
+    a, b, c = (jnp.ones((128, 64)), jnp.ones((64, 96)), jnp.ones((128, 96)))
+    out = _block_dgemm(a, b, c, jnp.float32(1.0), jnp.float32(0.5))
+    assert c.is_deleted() and not a.is_deleted() and not b.is_deleted()
+    np.testing.assert_allclose(np.asarray(out), 64.5)
+
+
 def test_in_core_switch():
     assert is_in_core(64, 64, 64, 1 << 20, 4)
     assert not is_in_core(1024, 1024, 1024, 1 << 20, 4)

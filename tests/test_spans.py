@@ -108,6 +108,24 @@ def test_ooc_gemm_spans_tile_the_call_in_both_modes(tmp_path, mode):
                    for h in by["ooc.exec.h2d"] for w in backs)
 
 
+def test_entry_copy_span_says_whether_the_result_was_reused(tmp_path):
+    A, B, C = operands()
+    rt = HostOocRuntime()
+    with profiled(tmp_path) as spans:
+        for _ in range(2):
+            out = ooc_gemm(A, B, C, 1.0, 0.5, budget_bytes=BUDGET,
+                           backend="host", runtime=rt)
+            del out                # the second call writes into the first
+    copies = [s for s in spans if s.name == "ooc.entry.copy_c"]
+    assert len(copies) == 2
+    # within each copy, the span of getting the result names the choice
+    for copy, name in zip(copies, ("ooc.entry.alloc_result",
+                                   "ooc.entry.reuse_result")):
+        inner = [s for s in spans if s.name.startswith("ooc.entry.")
+                 and s.name != "ooc.entry.copy_c" and s.within(copy)]
+        assert [s.name for s in inner] == [name]
+
+
 def test_in_core_ooc_gemm_is_one_span_without_executor(tmp_path):
     A, B, C = operands()
     with profiled(tmp_path) as spans:
