@@ -152,7 +152,8 @@ def ooc_gemm(
     tier of size ``budget_bytes``.
 
     backend: "host" (schedule-driven block streaming), "vmem" (Pallas kernel),
-    "mesh" (SUMMA ring over a mesh axis).
+    "mesh" (SUMMA ring over a mesh axis; ``budget_bytes`` bounds each
+    chip's working set, and operands in host memory get the result there).
 
     tune: ``None`` uses the hardcoded defaults above; ``"auto"`` asks an
     :class:`~repro.tune.tuner.AutoTuner` (``tuner`` or the process default)
@@ -205,12 +206,14 @@ def ooc_gemm(
             return out
         if backend == "mesh":
             # operands go from where they are straight to their shards,
-            # never whole onto the default device first
+            # never whole onto the default device first; host operands get
+            # their result back in host memory
             if C is None:
                 C = np.zeros((A.shape[0], B.shape[1]), dtype=A.dtype)
                 beta = 0.0
             rt = runtime or MeshOocRuntime(mesh)
-            return rt.gemm(A, B, C, alpha, beta, None)
+            return rt.gemm(A, B, C, alpha, beta, None,
+                           budget_bytes=budget_bytes)
         A = np.asarray(A) if backend == "host" else jnp.asarray(A)
         B = np.asarray(B) if backend == "host" else jnp.asarray(B)
         M, K = A.shape

@@ -1032,6 +1032,13 @@ class MeshOocRuntime(OocRuntime):
     through a ping-pong buffer with ``ppermute`` while the MXU consumes the
     current block — the paper's 2-stream overlap where the "PCIe link" is ICI
     and the "host memory" is the neighbours' HBM.
+
+    A call is three spans on the profiler's timeline: ``ooc.mesh.place``
+    (the shards put on their chips and landed), ``ooc.mesh.ring`` (the
+    SUMMA program run to completion) and, for operands in host memory,
+    ``ooc.mesh.gather`` (the sharded result copied into host memory).
+    ``last_place_bytes`` and ``last_gather_bytes`` count the bytes the
+    last call put on the mesh and brought back.
     """
 
     def __init__(self, mesh: Mesh, axis: str = "model",
@@ -1039,6 +1046,8 @@ class MeshOocRuntime(OocRuntime):
         self.mesh = mesh
         self.axis = axis
         self.device = device or Device("MESH", 0, 16 * 2**30)
+        self.last_place_bytes = 0
+        self.last_gather_bytes = 0
 
     @classmethod
     def from_device(cls, device: Device, *, mesh: Optional[Mesh] = None,
@@ -1047,16 +1056,58 @@ class MeshOocRuntime(OocRuntime):
             raise ValueError("MESH runtime needs a jax Mesh")
         return cls(mesh, device=device, **kw)
 
-    def gemm(self, A, B, C, alpha, beta, part=None, overlap: bool = True, **kw):
+    def working_set_bytes(self, M: int, N: int, K: int,
+                          bytes_per_el: int) -> int:
+        """Bytes a chip holds during a call: its A, B and C shards, the
+        ring's second B buffer and one step's product block."""
         Pn = self.mesh.shape[self.axis]
-        M, _ = A.shape
-        _, N = B.shape
+        m, n = M // Pn, N // Pn
+        return bytes_per_el * (m * K + 2 * K * n + m * N + m * n)
+
+    def gemm(self, A, B, C, alpha, beta, part=None, overlap: bool = True,
+             budget_bytes: Optional[int] = None, **kw):
+        """``alpha * A @ B + beta * C`` on the mesh.  The result is a
+        sharded ``jax.Array`` where A and B are both of that type, else
+        an ndarray in host memory.  A call whose per-chip working set is over
+        ``budget_bytes`` raises ``ValueError``.
+
+        The budget counts the tier's own buffers.  Operands the caller
+        already holds on the chips are on top of it, and so are the copies
+        of B and C made from them, since the program donates its B and C."""
+        Pn = self.mesh.shape[self.axis]
+        M, K = A.shape
+        K2, N = B.shape
+        if K != K2:
+            raise ValueError(f"inner dims mismatch: {A.shape} @ {B.shape}")
         if M % Pn or N % Pn:
             raise ValueError(f"SUMMA needs M,N divisible by mesh axis {Pn}")
-        args = [jax.device_put(x, s)
-                for x, s in zip((A, B, C), self.shardings())]
-        return self.program(overlap)(*args, jnp.float32(alpha),
-                                     jnp.float32(beta))
+        need = self.working_set_bytes(M, N, K, np.dtype(A.dtype).itemsize)
+        if budget_bytes is not None and need > budget_bytes:
+            raise ValueError(f"SUMMA needs {need} bytes a chip, over "
+                             f"budget_bytes = {budget_bytes}")
+        on_device = isinstance(A, jax.Array) and isinstance(B, jax.Array)
+        obs = get_observability()
+        place = sum(x.nbytes for x in (A, B, C))
+        sa, sb, sc = self.shardings()
+        with obs.span("ooc.mesh.place", cat="mesh", bytes=place):
+            # the program donates B and C: never the caller's own arrays
+            args = [jax.device_put(A, sa),
+                    jax.device_put(B, sb, may_alias=False),
+                    jax.device_put(C, sc, may_alias=False)]
+            jax.block_until_ready(args)
+        self.last_place_bytes = place
+        self.last_gather_bytes = 0
+        with obs.span("ooc.mesh.ring", cat="mesh"):
+            out, _ = self.program(overlap)(*args, jnp.float32(alpha),
+                                           jnp.float32(beta))
+            del args
+            out.block_until_ready()
+        if on_device:
+            return out
+        with obs.span("ooc.mesh.gather", cat="mesh", bytes=out.nbytes):
+            res = _gather(out)
+        self.last_gather_bytes = res.nbytes
+        return res
 
     def shardings(self) -> Tuple[NamedSharding, ...]:
         """Shardings of ``(A, B, C)``: A and C by row blocks, B by column
@@ -1064,10 +1115,23 @@ class MeshOocRuntime(OocRuntime):
         return _summa_shardings(self.mesh, self.axis)
 
     def program(self, overlap: bool = True):
-        """The jitted SUMMA ring ``(A, B, C, alpha, beta) -> C`` over this
-        runtime's mesh axis (one per mesh/axis/overlap, so repeated calls
-        reuse its compilation)."""
+        """The jitted SUMMA ring ``(A, B, C, alpha, beta) -> (C, B)`` over
+        this runtime's mesh axis (one per mesh/axis/overlap, so repeated
+        calls reuse its compilation), B and C donated.  Its XLA module is
+        ``jit_summa_ring``."""
         return _summa_program(self.mesh, self.axis, overlap)
+
+
+def _gather(out: jax.Array) -> np.ndarray:
+    """A sharded array copied into one writable host array, every shard's
+    copy started before the first is waited for."""
+    res = np.empty(out.shape, out.dtype)
+    shards = out.addressable_shards
+    for s in shards:
+        s.data.copy_to_host_async()
+    for s in shards:
+        res[s.index] = np.asarray(s.data)
+    return res
 
 
 def _summa_shardings(mesh: Mesh, axis: str) -> Tuple[NamedSharding, ...]:
@@ -1100,16 +1164,26 @@ def _summa_program(mesh: Mesh, axis: str, overlap: bool):
                 b_nxt = jax.lax.ppermute(b_cur, axis, perm)
             return b_nxt, acc
 
-        _, acc = jax.lax.fori_loop(0, Pn, step, (b_blk, c_blk))
-        return acc
+        # after Pn steps every B block is home again: returning it lets
+        # the ring turn in the donated B buffer instead of a copy of it
+        b_blk, acc = jax.lax.fori_loop(0, Pn, step, (b_blk, c_blk))
+        return acc, b_blk
 
     shardings = _summa_shardings(mesh, axis)
     specs = tuple(s.spec for s in shardings)
-    fn = jax.shard_map(ring_body, mesh=mesh, in_specs=specs + (P(), P()),
-                       out_specs=specs[2])
+    ring = jax.shard_map(ring_body, mesh=mesh, in_specs=specs + (P(), P()),
+                         out_specs=(specs[2], specs[1]))
+
+    def summa_ring(a, b, c, alpha, beta):
+        # named for the XLA module a device trace shows: jit_summa_ring
+        return ring(a, b, c, alpha, beta)
+
+    # B and C are donated and aliased to the outputs, so a chip holds its
+    # A, B and C shards, one more B block and a step's product
     scalar = NamedSharding(mesh, P())
-    return jax.jit(fn, in_shardings=shardings + (scalar, scalar),
-                   out_shardings=shardings[2])
+    return jax.jit(summa_ring, in_shardings=shardings + (scalar, scalar),
+                   out_shardings=(shardings[2], shardings[1]),
+                   donate_argnums=(1, 2))
 
 
 class RuntimeFactory:

@@ -2,13 +2,17 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 
 def gemm_ref(a, b, c=None, alpha: float = 1.0, beta: float = 0.0):
-    """DGEMM contract: alpha * a @ b + beta * c, fp32 accumulation."""
+    """DGEMM contract: alpha * a @ b + beta * c, fp32 accumulation, the
+    product at HIGHEST precision (a TPU otherwise multiplies fp32 in one
+    bf16 pass)."""
     acc = jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32),
+                  precision=jax.lax.Precision.HIGHEST,
                   preferred_element_type=jnp.float32)
     out = alpha * acc
     if c is not None:

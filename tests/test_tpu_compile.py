@@ -103,3 +103,30 @@ def test_block_dgemm_compiles_at_main_phase_block(one_chip,
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16 * 2**30, used
+
+
+def test_summa_ring_fits_its_working_set_at_the_cells_size(topo,
+                                                          no_compile_cache):
+    """The MESH tier's ring at 40960³ fp32 HIGHEST on four chips holds no
+    more a chip than ``working_set_bytes``, the figure its budget check
+    uses: B and C donated, one more B block and a step's product."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.core.runtime import MeshOocRuntime
+
+    n = 40960
+    mesh = Mesh(np.array(topo.devices), ("model",),
+                axis_types=(AxisType.Auto,))
+    rt = MeshOocRuntime(mesh)
+    shapes = [_sds((n, n), jnp.float32, s) for s in rt.shardings()]
+    scalar = _sds((), jnp.float32, jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec()))
+    with jax.default_matmul_precision("highest"):
+        compiled = rt.program().lower(*shapes, scalar, scalar).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    need = rt.working_set_bytes(n, n, n, 4)
+    assert need - 2**20 < used <= need + 2**20, (used, need)
+    assert "collective-permute" in compiled.as_text()
