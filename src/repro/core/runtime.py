@@ -171,15 +171,19 @@ def _take(arr, ref: SliceRef):
     return arr.T if ref.transpose else arr
 
 
-def _put_block(host_block) -> jax.Array:
-    """One H2D transfer, landed on return.  The TPU runtime stages H2D
-    copies in a fixed premapped host buffer (4 GiB by default).  Transfers
-    left in flight while the host's issue loop runs ahead overflow it, and
-    a strided block (a column block of a row-major operand) that does not
-    fit there takes a host staging copy as large as itself.  Waiting keeps
-    one transfer in flight.  Compute dispatched earlier keeps running
-    meanwhile."""
-    return jnp.asarray(host_block).block_until_ready()
+def _put_block(host_block, device: Optional[jax.Device] = None
+               ) -> jax.Array:
+    """One H2D transfer, landed on return: on the default device, or on
+    ``device`` where one is given (a shard of the mesh tier).  The TPU
+    runtime stages H2D copies in a fixed premapped host buffer (4 GiB by
+    default).  Transfers left in flight while the host's issue loop runs
+    ahead overflow it, and a strided block (a column block of a row-major
+    operand) that does not fit there takes a host staging copy as large as
+    itself.  Waiting keeps one transfer in flight.  Compute dispatched
+    earlier keeps running meanwhile."""
+    if device is None:
+        return jnp.asarray(host_block).block_until_ready()
+    return jax.device_put(host_block, device).block_until_ready()
 
 
 def _load(st: ExecState, ref: SliceRef, nbytes: int) -> jax.Array:
@@ -1033,12 +1037,23 @@ class MeshOocRuntime(OocRuntime):
     current block — the paper's 2-stream overlap where the "PCIe link" is ICI
     and the "host memory" is the neighbours' HBM.
 
+    Placement keeps ``_put_block``'s rule.  An operand in host memory is
+    cut into its sharding's per-chip slices (B's column blocks stay
+    strided views), and each slice is put on its chip and landed before
+    the next is issued, so one transfer at a time passes through the TPU
+    runtime's premapped staging buffer; the landed shards are assembled
+    into the global array the program takes, with no reshard.  An operand
+    already on the chips is resharded there by ``jax.device_put``, with no
+    host staging.
+
     A call is three spans on the profiler's timeline: ``ooc.mesh.place``
-    (the shards put on their chips and landed), ``ooc.mesh.ring`` (the
-    SUMMA program run to completion) and, for operands in host memory,
+    (the shards put on their chips and landed, one ``ooc.mesh.put`` span
+    per host-to-chip transfer inside it), ``ooc.mesh.ring`` (the SUMMA
+    program run to completion) and, for operands in host memory,
     ``ooc.mesh.gather`` (the sharded result copied into host memory).
     ``last_place_bytes`` and ``last_gather_bytes`` count the bytes the
-    last call put on the mesh and brought back.
+    last call put on the mesh and brought back, ``last_place_transfers``
+    the host-to-chip transfers its placement made one at a time.
     """
 
     def __init__(self, mesh: Mesh, axis: str = "model",
@@ -1048,6 +1063,7 @@ class MeshOocRuntime(OocRuntime):
         self.device = device or Device("MESH", 0, 16 * 2**30)
         self.last_place_bytes = 0
         self.last_gather_bytes = 0
+        self.last_place_transfers = 0
 
     @classmethod
     def from_device(cls, device: Device, *, mesh: Optional[Mesh] = None,
@@ -1089,11 +1105,12 @@ class MeshOocRuntime(OocRuntime):
         obs = get_observability()
         place = sum(x.nbytes for x in (A, B, C))
         sa, sb, sc = self.shardings()
+        self.last_place_transfers = 0
         with obs.span("ooc.mesh.place", cat="mesh", bytes=place):
             # the program donates B and C: never the caller's own arrays
-            args = [jax.device_put(A, sa),
-                    jax.device_put(B, sb, may_alias=False),
-                    jax.device_put(C, sc, may_alias=False)]
+            args = [self._place(A, sa, obs),
+                    self._place(B, sb, obs, may_alias=False),
+                    self._place(C, sc, obs, may_alias=False)]
             jax.block_until_ready(args)
         self.last_place_bytes = place
         self.last_gather_bytes = 0
@@ -1108,6 +1125,26 @@ class MeshOocRuntime(OocRuntime):
             res = _gather(out)
         self.last_gather_bytes = res.nbytes
         return res
+
+    def _place(self, x, sharding: NamedSharding, obs,
+               may_alias: Optional[bool] = None) -> jax.Array:
+        """``x`` on the chips in ``sharding``.  A device operand is
+        resharded there (``may_alias=False`` copies it); a host operand
+        goes a slice at a time, each landed before the next is issued
+        (``_put_block``), and a host array never aliases the result."""
+        if isinstance(x, jax.Array):
+            return jax.device_put(x, sharding, may_alias=may_alias)
+        x = np.asarray(x)
+        shards = []
+        for dev, idx in sharding.addressable_devices_indices_map(
+                x.shape).items():
+            blk = x[idx]
+            with obs.span("ooc.mesh.put", cat="mesh", bytes=blk.nbytes,
+                          device=dev.id):
+                shards.append(_put_block(blk, dev))
+            self.last_place_transfers += 1
+        return jax.make_array_from_single_device_arrays(x.shape, sharding,
+                                                        shards)
 
     def shardings(self) -> Tuple[NamedSharding, ...]:
         """Shardings of ``(A, B, C)``: A and C by row blocks, B by column

@@ -6,9 +6,11 @@ that a brand-new kernel (scaled block copy) rides the DSL with ~20 lines and
 no interpreter code.
 """
 
+import jax
 import numpy as np
 import pytest
 
+from repro.core import runtime
 from repro.core import (
     BlockRef,
     ComputeStage,
@@ -125,3 +127,27 @@ def test_new_kernel_via_spec(rng):
     ScheduleExecutor().run(sched, operands={"X": X}, outputs={"Y": out},
                            ctx={"gamma": 3.0})
     np.testing.assert_allclose(out, 3.0 * X, rtol=0, atol=0)
+
+
+def test_put_block_without_a_device_is_jnp_asarray_landed(monkeypatch):
+    # the host tier's H2D rule, byte for byte: jnp.asarray on the default
+    # device, waited for before the next block is issued
+    seen = []
+    asarray = runtime.jnp.asarray
+    monkeypatch.setattr(runtime.jnp, "asarray",
+                        lambda x: seen.append(x) or asarray(x))
+    host = np.arange(48, dtype=np.float32).reshape(6, 8)[:, 2:5]
+    out = runtime._put_block(host)
+    assert len(seen) == 1 and seen[0] is host
+    assert out.is_ready() and not out.committed
+    assert out.devices() == {jax.devices()[0]}
+    np.testing.assert_array_equal(np.asarray(out), host)
+
+
+def test_put_block_with_a_device_lands_there():
+    device = jax.devices()[-1]
+    host = np.arange(48, dtype=np.float32).reshape(6, 8)[:, 2:5]
+    out = runtime._put_block(host, device)
+    assert out.is_ready() and out.committed
+    assert out.devices() == {device}
+    np.testing.assert_array_equal(np.asarray(out), host)

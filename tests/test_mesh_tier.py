@@ -52,12 +52,26 @@ def _child() -> dict:
                          axis_types=(AxisType.Auto,))
     rt = MeshOocRuntime(mesh)
     obs = get_observability()
+    placed = []
+
+    def program(overlap=True):
+        # the arrays the ring is handed, as placed
+        run = MeshOocRuntime.program(rt, overlap)
+
+        def seen(a, b, c, alpha, beta):
+            placed.append([x.sharding == s and x.sharding.spec == s.spec
+                           for x, s in zip((a, b, c), rt.shardings())])
+            return run(a, b, c, alpha, beta)
+        return seen
+
+    rt.program = program
     out = {"devices": len(jax.devices()), "cases": {}}
     for i, (name, (M, N, K, alpha, beta, with_c)) in enumerate(
             CASES.items()):
         A, B, C = _operands(M, N, K, i)
         kept = [x.copy() for x in (A, B, C)]
         budget = rt.working_set_bytes(M, N, K, 4)
+        placed.clear()
         obs.start_trace()
         try:
             got = ooc_gemm(A, B, C if with_c else None, alpha, beta,
@@ -78,12 +92,15 @@ def _child() -> dict:
             "f64_err": float(np.max(np.abs(got - exact) / bound)),
             "place_bytes": rt.last_place_bytes,
             "gather_bytes": rt.last_gather_bytes,
+            "place_transfers": rt.last_place_transfers,
+            "placed_in_shardings": list(placed),
             "want_place_bytes": A.nbytes + B.nbytes + c.nbytes,
             "want_gather_bytes": M * N * 4,
             "operands_kept": all(np.array_equal(x, y)
                                  for x, y in zip((A, B, C), kept)),
             "spans": [[s.name, ids.get(s.parent_id), dict(s.args)]
-                      for s in spans]}
+                      for s in spans],
+            "times": [[s.name, s.start, s.end] for s in spans]}
     host = _operands(256, 256, 256, 9)
     # already in the program's shardings: A is used as it is, B and C are
     # copied, since the program donates them
@@ -94,6 +111,7 @@ def _child() -> dict:
         "type": type(got).__name__,
         "on_devices": len(got.sharding.device_set),
         "gather_bytes": rt.last_gather_bytes,
+        "place_transfers": rt.last_place_transfers,
         "operands_kept": all(not x.is_deleted()
                              and np.array_equal(np.asarray(x), y)
                              for x, y in zip((A, B, C), host))}
@@ -153,13 +171,44 @@ def test_place_and_gather_bytes_are_exact(child, case):
 @pytest.mark.parametrize("case", CASES)
 def test_mesh_spans_in_order_under_the_call(child, case):
     got = child["cases"][case]
-    assert got["spans"] == [
+    puts = [s for s in got["spans"] if s[0] == "ooc.mesh.put"]
+    assert [s for s in got["spans"] if s[0] != "ooc.mesh.put"] == [
         ["ooc.gemm", None, {}],
         ["ooc.mesh.place", "ooc.gemm",
          {"bytes": str(got["want_place_bytes"])}],
         ["ooc.mesh.ring", "ooc.gemm", {}],
         ["ooc.mesh.gather", "ooc.gemm",
          {"bytes": str(got["want_gather_bytes"])}]]
+    assert got["spans"][2:2 + len(puts)] == puts
+    assert all(s[1] == "ooc.mesh.place" for s in puts)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_host_operands_are_placed_one_transfer_at_a_time(child, case):
+    # A, B and C, one shard a chip each: twelve transfers, each inside
+    # the placement and landed before the next begins
+    got = child["cases"][case]
+    assert got["place_transfers"] == 3 * DEVICES
+    puts = [t for t in got["times"] if t[0] == "ooc.mesh.put"]
+    (place,) = [t for t in got["times"] if t[0] == "ooc.mesh.place"]
+    assert len(puts) == got["place_transfers"]
+    assert all(place[1] <= t[1] <= t[2] <= place[2] for t in puts)
+    assert all(a[2] <= b[1] for a, b in zip(puts, puts[1:]))
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_put_spans_carry_every_placed_byte(child, case):
+    got = child["cases"][case]
+    puts = [s[2] for s in got["spans"] if s[0] == "ooc.mesh.put"]
+    assert sum(int(p["bytes"]) for p in puts) == got["place_bytes"]
+    # each chip is sent its shard of A, of B and of C
+    devices = sorted(int(p["device"]) for p in puts)
+    assert devices == sorted(list(range(DEVICES)) * 3)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_placed_arrays_carry_the_programs_shardings(child, case):
+    assert child["cases"][case]["placed_in_shardings"] == [[True] * 3]
 
 
 def test_device_operands_give_a_sharded_device_result(child):
@@ -167,6 +216,10 @@ def test_device_operands_give_a_sharded_device_result(child):
     assert got["type"] == "ArrayImpl" and got["on_devices"] == DEVICES
     assert got["gather_bytes"] == 0
     assert got["operands_kept"]
+
+
+def test_device_operands_make_no_host_transfer(child):
+    assert child["device_operands"]["place_transfers"] == 0
 
 
 def test_a_budget_below_the_working_set_raises(child):
