@@ -8,7 +8,6 @@ Prints ``name,us_per_call,derived`` CSV:
   bench_pipeline    — claims C3+C5 (vs CUBLAS-XT-style vendor schedule;
                                  stream-width vs hardware; Fig. 5a/5b/5c)
   bench_loc         — claim C4  (75 % LOC reduction)
-  bench_roofline    — §Roofline table from the dry-run artifacts
   bench_validate    — validate_schedule scaling guard (linear-ish)
   bench_simulate    — simulate() ready-queue guard + reference equivalence
   bench_tune        — autotuner: tuned vs default makespans (C5 selection)
@@ -60,7 +59,7 @@ def _write_sidecar(obs, mod_name: str) -> None:
 def main() -> None:
     from benchmarks import (bench_exec, bench_fault, bench_hybrid,
                             bench_loc, bench_overhead, bench_pipeline,
-                            bench_reuse, bench_roofline, bench_simulate,
+                            bench_reuse, bench_simulate,
                             bench_transition, bench_tune, bench_validate)
     from repro.compile_cache import enable_compile_cache
     from repro.obs import get_observability
@@ -70,7 +69,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     failures = 0
     for mod in (bench_overhead, bench_transition, bench_pipeline,
-                bench_loc, bench_roofline, bench_validate, bench_simulate,
+                bench_loc, bench_validate, bench_simulate,
                 bench_tune, bench_hybrid, bench_reuse, bench_fault,
                 bench_exec):
         mod_name = mod.__name__.rsplit(".", 1)[-1]

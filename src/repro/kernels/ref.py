@@ -59,23 +59,3 @@ def decode_attention_ref(q, k, v, length=None):
     p = p / p.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bhs,bshd->bhd", p, vb.astype(jnp.float32))
     return out.astype(q.dtype)
-
-
-def causal_attention_ref(q, k, v):
-    """Full-sequence causal GQA attention oracle.
-
-    q: (B, S, H, d); k, v: (B, S, Hkv, d).  Returns (B, S, H, d).
-    """
-    B, S, H, d = q.shape
-    hkv = k.shape[2]
-    group = H // hkv
-    kb = jnp.repeat(k, group, axis=2)
-    vb = jnp.repeat(v, group, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   kb.astype(jnp.float32)) / np.sqrt(d)
-    mask = jnp.tril(jnp.ones((S, S), dtype=bool))
-    s = jnp.where(mask[None, None], s, -jnp.inf)
-    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
-    p = p / p.sum(axis=-1, keepdims=True)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vb.astype(jnp.float32))
-    return out.astype(q.dtype)

@@ -151,3 +151,40 @@ def test_put_block_with_a_device_lands_there():
     assert out.is_ready() and out.committed
     assert out.devices() == {device}
     np.testing.assert_array_equal(np.asarray(out), host)
+
+
+# The host-coherence rule: a write-back still in flight must land before an
+# H2D that re-reads any element of it.  Slices are half-open (start, size);
+# None is the operand's full extent.
+OVERLAP_CASES = {
+    "disjoint_rows": (SliceRef("C", 0, rows=(0, 8)),
+                      SliceRef("C", 1, rows=(32, 8)), (64, 64), False),
+    "disjoint_cols": (SliceRef("C", 0, cols=(0, 16)),
+                      SliceRef("C", 1, cols=(32, 16)), (64, 64), False),
+    "corner": (SliceRef("C", 0, rows=(0, 16), cols=(0, 16)),
+               SliceRef("C", 1, rows=(8, 16), cols=(8, 16)), (64, 64), True),
+    "containment": (SliceRef("C", 0, rows=(0, 32), cols=(0, 32)),
+                    SliceRef("C", 1, rows=(8, 4), cols=(8, 4)), (64, 64),
+                    True),
+    "identical": (SliceRef("C", 0, rows=(16, 16), cols=(16, 16)),
+                  SliceRef("C", 0, rows=(16, 16), cols=(16, 16)), (64, 64),
+                  True),
+    "touching_is_disjoint": (SliceRef("C", 0, rows=(0, 16)),
+                             SliceRef("C", 1, rows=(16, 16)), (64, 64),
+                             False),
+    "none_is_full_extent": (SliceRef("C", 0),
+                            SliceRef("C", 1, rows=(60, 4), cols=(60, 4)),
+                            (64, 64), True),
+    "other_operand": (SliceRef("C", 0, rows=(0, 16), cols=(0, 16)),
+                      SliceRef("A", 0, rows=(0, 16), cols=(0, 16)), (64, 64),
+                      False),
+    "one_dimensional": (SliceRef("out", 0, rows=(0, 8)),
+                        SliceRef("out", 1, rows=(4, 8)), (64,), True),
+}
+
+
+@pytest.mark.parametrize("case", OVERLAP_CASES)
+def test_spans_overlap_truth_table(case):
+    a, b, shape, want = OVERLAP_CASES[case]
+    assert runtime._spans_overlap(a, b, shape) is want
+    assert runtime._spans_overlap(b, a, shape) is want

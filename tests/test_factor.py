@@ -15,6 +15,7 @@ from repro.core import (compile_factor_pipeline, factor_pipeline_spec,
                         ooc_cholesky, ooc_lu, schedule_stats,
                         validate_schedule)
 from repro.core.api import hclOocFactor
+from repro.core.runtime import apply_panel_pivots, getrf_panel
 
 
 def _spd(rng, n, dtype=np.float32, cond=None):
@@ -251,3 +252,54 @@ def test_factor_rejects_non_square(rng):
         ooc_cholesky(rng.standard_normal((64, 32)), budget_bytes=1 << 20)
     with pytest.raises(ValueError, match="square"):
         ooc_lu(rng.standard_normal((64, 32)), budget_bytes=1 << 20)
+
+
+def _panel(kind):
+    rng = np.random.default_rng(7)
+    if kind == "tall":
+        return rng.standard_normal((24, 8))
+    if kind == "square":
+        return rng.standard_normal((16, 16))
+    p = rng.standard_normal((12, 4))      # "row_swap": column 0 peaks last
+    p[-1, 0] = 100.0
+    return p
+
+
+@pytest.mark.parametrize("kind", ["tall", "square", "row_swap"])
+def test_getrf_panel_matches_lapack(kind):
+    """The in-core panel factor the LU pipeline runs: the same pivots and
+    the same packed L\\U as LAPACK's getrf."""
+    scipy_linalg = pytest.importorskip("scipy.linalg")
+    panel = _panel(kind)
+    lu, piv = scipy_linalg.lu_factor(panel)
+    buf = panel.copy()
+    got = getrf_panel(buf)
+    assert np.array_equal(got, piv)
+    np.testing.assert_allclose(buf, lu, rtol=1e-12, atol=1e-12)
+    if kind == "row_swap":
+        assert got[0] == panel.shape[0] - 1
+
+
+@pytest.mark.parametrize("k0, k1, dominant", [(0, 8, False), (8, 16, False),
+                                               (8, 16, True)],
+                         ids=["first_panel", "middle_panel", "no_swap"])
+def test_apply_panel_pivots_permutes_the_rows_outside_the_panel(
+        k0, k1, dominant):
+    """Replaying a panel's pivots leaves its own columns alone and moves
+    every other column's rows k0: as LAPACK's permutation of the panel."""
+    scipy_linalg = pytest.importorskip("scipy.linalg")
+    n = 24
+    A0 = np.random.default_rng(k0).standard_normal((n, n))
+    if dominant:
+        A0 += 100.0 * np.eye(n)
+    p, _, _ = scipy_linalg.lu(A0[k0:, k0:k1])
+    want = np.arange(n)
+    want[k0:] = k0 + p.argmax(axis=0)     # A0[k0:][local] == L @ U
+    A = A0.copy()
+    perm = np.arange(n)
+    apply_panel_pivots(A, getrf_panel(A0[k0:, k0:k1].copy()), k0, k1, perm)
+    assert np.array_equal(perm, want)
+    assert np.array_equal(A[:, k0:k1], A0[:, k0:k1])
+    assert np.array_equal(A[:, :k0], A0[want, :k0])
+    assert np.array_equal(A[:, k1:], A0[want, k1:])
+    assert dominant == np.array_equal(perm, np.arange(n))

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -26,6 +27,15 @@ CASES = {
     "rectangular": (128, 256, 384, 1.0, 0.5, True),
     "c_none": (256, 256, 256, 1.0, 0.5, False),
     "alpha_beta": (256, 256, 256, -0.75, 2.5, True),
+}
+# operands the ring cannot shard: (A's shape, B's shape, the refusal)
+REFUSALS = {
+    "inner_dims": ((256, 128), (256, 256),
+                   r"inner dims mismatch: \(256, 128\) @ \(256, 256\)"),
+    "m_not_divisible": ((254, 256), (256, 256),
+                        f"SUMMA needs M,N divisible by mesh axis {DEVICES}"),
+    "n_not_divisible": ((256, 256), (256, 254),
+                        f"SUMMA needs M,N divisible by mesh axis {DEVICES}"),
 }
 
 
@@ -124,6 +134,15 @@ def _child() -> dict:
     except ValueError as e:
         out["over_budget"] = {"need": need, "budget": need - 1,
                               "message": str(e)}
+    out["refusals"] = {}
+    for name, (a_shape, b_shape, _) in REFUSALS.items():
+        try:
+            ooc_gemm(np.ones(a_shape, np.float32),
+                     np.ones(b_shape, np.float32), None, 1.0, 0.0,
+                     budget_bytes=2**30, backend="mesh", runtime=rt)
+            out["refusals"][name] = None
+        except Exception as e:  # its type is checked below
+            out["refusals"][name] = [type(e).__name__, str(e)]
     return out
 
 
@@ -227,6 +246,14 @@ def test_a_budget_below_the_working_set_raises(child):
     assert got is not None, "an over-budget call ran"
     assert str(got["need"]) in got["message"]
     assert str(got["budget"]) in got["message"]
+
+
+@pytest.mark.parametrize("case", REFUSALS)
+def test_operands_the_ring_cannot_shard_are_refused(child, case):
+    got = child["refusals"][case]
+    assert got is not None, "the call ran"
+    assert got[0] == "ValueError"
+    assert re.search(REFUSALS[case][2], got[1]), got[1]
 
 
 if __name__ == "__main__":

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import VmemOocRuntime, is_in_core, ooc_gemm, ooc_syrk
+from repro.core import (VmemOocRuntime, is_in_core, ooc_gemm, ooc_syrk,
+                        plan_for_device, plan_gemm_partition)
 from repro.core.api import (hclDeviceFactory, hclGetMemSize,
                             hclMatrixPartitioner, hclRuntimeFactory)
 from repro.core.ooc_attention import ooc_attention
@@ -95,6 +96,25 @@ def test_block_product_takes_its_accumulators_memory():
 def test_in_core_switch():
     assert is_in_core(64, 64, 64, 1 << 20, 4)
     assert not is_in_core(1024, 1024, 1024, 1 << 20, 4)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("spare, fits", [(0, True), (-1, False)],
+                         ids=["at_budget", "one_element_over"])
+def test_in_core_switch_is_exact_at_the_budget(dtype, spare, fits):
+    M, N, K = 64, 48, 32
+    bpe = np.dtype(dtype).itemsize
+    budget = (M * K + K * N + M * N + spare) * bpe
+    assert is_in_core(M, N, K, budget, bpe) is fits
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_plan_for_device_partitions_at_its_memory(dtype):
+    dev = hclDeviceFactory.create("HBM", 0, mem_bytes=300_000)
+    bpe = np.dtype(dtype).itemsize
+    part = plan_for_device(512, 256, 128, dev, bpe)
+    assert part == plan_gemm_partition(512, 256, 128, 300_000, bpe)
+    assert part.h * part.w > 1
 
 
 def test_hcl_facade(rng):
