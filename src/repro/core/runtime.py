@@ -908,8 +908,40 @@ def _sole_refcount() -> int:
 _SOLE_REFCOUNT = _sole_refcount()
 
 
+class _KeepsResult:
+    """A runtime that keeps the last host result it returned, to write the
+    next result into once the caller has dropped it.  Writing into pages
+    already mapped spares the kernel handing over and zeroing fresh ones.
+    The rule (:meth:`_take_idle`) is the same on every tier that keeps
+    one; :meth:`release` drops the kept result."""
+
+    _idle: Optional[np.ndarray] = None
+
+    def release(self) -> None:
+        """Drop the last result this runtime returned.  The runtime keeps
+        it to write the next result into; until the next call or this one,
+        a result the caller dropped stays mapped."""
+        self._idle = None
+
+    def _take_idle(self, shape, dtype, *arrays) -> Optional[np.ndarray]:
+        """The kept result, taken from the runtime, where it may be
+        written: nothing else refers to it (the refcount rule of numpy's
+        ``ndarray.resize``), it has ``shape`` and ``dtype`` and shares no
+        memory with ``arrays``, the call's own.  Else ``None``, and the
+        kept result is dropped here, before the caller makes a fresh one.
+        The count is read on the local that took it from ``self``, a frame
+        of the shape ``_SOLE_REFCOUNT`` is measured in."""
+        idle, self._idle = self._idle, None
+        if (idle is not None
+                and sys.getrefcount(idle) == _SOLE_REFCOUNT
+                and idle.shape == tuple(shape) and idle.dtype == dtype
+                and not any(np.may_share_memory(idle, x) for x in arrays)):
+            return idle
+        return None
+
+
 @register_runtime("HBM")
-class HostOocRuntime(OocRuntime):
+class HostOocRuntime(_KeepsResult, OocRuntime):
     """Host-driven block streaming: builds (or accepts) a pipeline schedule
     and hands it to the shared :class:`ScheduleExecutor` — no private
     interpreter loop.  On real hardware JAX's async dispatch overlaps the
@@ -926,34 +958,17 @@ class HostOocRuntime(OocRuntime):
                  executor: Optional[ScheduleExecutor] = None):
         self.device = device or Device("HBM", 0, 16 * 2**30)
         self.executor = executor or ScheduleExecutor()
-        self._idle: Optional[np.ndarray] = None
-
-    def release(self) -> None:
-        """Drop the last result this runtime returned.  The runtime keeps
-        it to write the next result into; until the next call or this one,
-        a result the caller dropped stays mapped."""
-        self._idle = None
 
     def _result(self, C: np.ndarray, *operands: np.ndarray) -> np.ndarray:
         """The array a call returns, holding a copy of ``C``: the last
-        result this runtime returned, where nothing else refers to it (the
-        refcount rule of numpy's ``ndarray.resize``), it has ``C``'s shape
-        and dtype and shares no memory with the call's arrays; else a fresh
-        one, made after the old one is dropped.  Writing into pages already
-        mapped spares the kernel handing over and zeroing fresh ones.  The
-        ``ooc.entry.copy_c`` span covers getting the result and the copy;
-        within it ``ooc.entry.reuse_result`` or ``ooc.entry.alloc_result``
-        covers getting the result and names the choice."""
-        idle, self._idle = self._idle, None
-        reuse = (idle is not None
-                 and sys.getrefcount(idle) == _SOLE_REFCOUNT
-                 and idle.shape == C.shape and idle.dtype == C.dtype
-                 and not any(np.may_share_memory(idle, x)
-                             for x in (C, *operands)))
-        if not reuse:
-            idle = None            # dropped before a fresh one is made
+        result this runtime returned where :meth:`_take_idle` allows it,
+        else a fresh one.  The ``ooc.entry.copy_c`` span covers getting
+        the result and the copy; within it ``ooc.entry.reuse_result`` or
+        ``ooc.entry.alloc_result`` covers getting the result and names the
+        choice."""
+        idle = self._take_idle(C.shape, C.dtype, C, *operands)
         with get_observability().span("ooc.entry.copy_c", cat="entry"):
-            if reuse:
+            if idle is not None:
                 with annotate("ooc.entry.reuse_result"):
                     out = idle
             else:
@@ -1028,7 +1043,7 @@ class VmemOocRuntime(OocRuntime):
 
 
 @register_runtime("MESH")
-class MeshOocRuntime(OocRuntime):
+class MeshOocRuntime(_KeepsResult, OocRuntime):
     """Mesh tier: SUMMA ring over ICI.
 
     The operands are sharded across a 1-D submesh (A by row blocks, B by
@@ -1051,6 +1066,18 @@ class MeshOocRuntime(OocRuntime):
     per host-to-chip transfer inside it), ``ooc.mesh.ring`` (the SUMMA
     program run to completion) and, for operands in host memory,
     ``ooc.mesh.gather`` (the sharded result copied into host memory).
+    Within the gather ``ooc.mesh.reuse_result`` or ``ooc.mesh.alloc_result``
+    covers getting the host result and names the choice.
+
+    For host operands the runtime keeps the last result it returned and
+    gathers the next result of the same shape and dtype into it, by the
+    host tier's rule (:meth:`_take_idle`); :meth:`release` drops it.  This
+    spares the gather a result's worth of fresh host pages a call, but only
+    for a caller that holds one runtime across calls of one shape and drops
+    each result before the next call.  ``ooc_gemm(..., runtime=None)``
+    makes a runtime a call, so every call allocates; device operands give a
+    sharded ``jax.Array`` and the runtime keeps nothing of them.
+
     ``last_place_bytes`` and ``last_gather_bytes`` count the bytes the
     last call put on the mesh and brought back, ``last_place_transfers``
     the host-to-chip transfers its placement made one at a time.
@@ -1121,9 +1148,11 @@ class MeshOocRuntime(OocRuntime):
             out.block_until_ready()
         if on_device:
             return out
+        host = [x for x in (A, B, C) if not isinstance(x, jax.Array)]
         with obs.span("ooc.mesh.gather", cat="mesh", bytes=out.nbytes):
-            res = _gather(out)
+            res = _gather(out, self._take_idle(out.shape, out.dtype, *host))
         self.last_gather_bytes = res.nbytes
+        self._idle = res
         return res
 
     def _place(self, x, sharding: NamedSharding, obs,
@@ -1159,10 +1188,18 @@ class MeshOocRuntime(OocRuntime):
         return _summa_program(self.mesh, self.axis, overlap)
 
 
-def _gather(out: jax.Array) -> np.ndarray:
+def _gather(out: jax.Array, idle: Optional[np.ndarray]) -> np.ndarray:
     """A sharded array copied into one writable host array, every shard's
-    copy started before the first is waited for."""
-    res = np.empty(out.shape, out.dtype)
+    copy started before the first is waited for.  The array is ``idle``, a
+    kept result free to be written, or else a fresh one; an
+    ``ooc.mesh.reuse_result`` or ``ooc.mesh.alloc_result`` span covers
+    getting it."""
+    if idle is not None:
+        with annotate("ooc.mesh.reuse_result"):
+            res = idle
+    else:
+        with annotate("ooc.mesh.alloc_result"):
+            res = np.empty(out.shape, out.dtype)
     shards = out.addressable_shards
     for s in shards:
         s.data.copy_to_host_async()

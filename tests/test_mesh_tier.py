@@ -3,11 +3,14 @@ reference, on four virtual CPU devices.
 
 The device count is fixed when JAX starts, so the tier runs in one child
 process (this file run as a script) that prints what it saw as one JSON
-object; the tests below check that object, case by case.
+object; the tests below check that object, case by case.  The child also
+runs the scenarios of the runtime's kept result (``RECYCLED``) under one
+profiler session, and reads back which result span each gather holds.
 """
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import re
@@ -36,6 +39,19 @@ REFUSALS = {
                         f"SUMMA needs M,N divisible by mesh axis {DEVICES}"),
     "n_not_divisible": ((256, 256), (256, 254),
                         f"SUMMA needs M,N divisible by mesh axis {DEVICES}"),
+}
+
+# scenarios of the runtime's kept result, each started from a runtime that
+# keeps nothing, and what each gather of the scenario gets, in order
+RECYCLED = {
+    "dropped": ("alloc", "reuse"),
+    "held": ("alloc", "alloc"),
+    "view": ("alloc", "alloc"),
+    "passed_as_c": ("alloc", "alloc", "alloc"),
+    "new_shape": ("alloc", "alloc"),
+    "released": ("alloc", "alloc"),
+    "no_runtime": ("alloc", "alloc"),
+    "device_operands": (),
 }
 
 
@@ -143,7 +159,143 @@ def _child() -> dict:
             out["refusals"][name] = None
         except Exception as e:  # its type is checked below
             out["refusals"][name] = [type(e).__name__, str(e)]
+    out["recycled"] = _recycled(mesh)
     return out
+
+
+def _ptr(a: np.ndarray) -> int:
+    return a.__array_interface__["data"][0]
+
+
+def _recycled(mesh) -> dict:
+    """Every ``RECYCLED`` scenario on one runtime, each inside a
+    ``probe.<scenario>`` annotation of one profiler session: what the
+    scenario saw, each call's error against float64 in units of the
+    summation-order bound, and, per gather of the scenario, the names of
+    the ``ooc.mesh.*_result`` spans inside it."""
+    import tempfile
+    import weakref
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    from repro.core import MeshOocRuntime, ooc_gemm
+    from repro.kernels import ref
+
+    rt = MeshOocRuntime(mesh)
+    seeds = iter(range(100, 200))
+    seen = {name: {"errs": []} for name in RECYCLED}
+
+    def call(name, M=256, C=None, runtime=rt):
+        A, B, C0 = _operands(M, 256, 256, next(seeds))
+        C = C0 if C is None else C
+        want = A.astype(np.float64) @ B + 0.5 * np.asarray(C, np.float64)
+        got = ooc_gemm(A, B, C, 1.0, 0.5, budget_bytes=2**30,
+                       backend="mesh", mesh=mesh, runtime=runtime)
+        bound = ref.gemm_error_bound(A, B, C, 1.0, 0.5)
+        seen[name]["errs"].append(float(np.max(np.abs(got - want) / bound)))
+        return got
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as log_dir:
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        try:
+            with TraceAnnotation("probe.dropped"):
+                rt.release()
+                first = call("dropped")
+                kept, at = weakref.ref(first), _ptr(first)
+                del first
+                second = call("dropped")
+                seen["dropped"].update(same_array=second is kept(),
+                                       same_pointer=_ptr(second) == at)
+                del second
+            for name in ("held", "view"):
+                with TraceAnnotation(f"probe.{name}"):
+                    rt.release()
+                    held = call(name)
+                    if name == "view":
+                        held = held[:3]      # the result itself dropped
+                    before = held.copy()
+                    other = call(name)
+                    seen[name].update(
+                        unchanged=bool(np.array_equal(held, before)),
+                        shares=bool(np.shares_memory(held, other)))
+                    del held, other
+            with TraceAnnotation("probe.passed_as_c"):
+                # the caller's loop C = ooc_gemm(A, B, C, ...): the C of a
+                # call is alive until it returns, so never written into
+                rt.release()
+                C = call("passed_as_c")
+                moved = []
+                for _ in range(2):
+                    at = _ptr(C)
+                    C = call("passed_as_c", C=C)
+                    moved.append(_ptr(C) != at)
+                seen["passed_as_c"]["moved"] = moved
+                del C
+            with TraceAnnotation("probe.new_shape"):
+                rt.release()
+                r = call("new_shape")
+                gone = weakref.ref(r)
+                del r
+                kept = gone() is not None
+                other = call("new_shape", M=128)
+                seen["new_shape"].update(kept_until_next_call=kept,
+                                         dropped=gone() is None,
+                                         shape=list(other.shape))
+                del other
+            with TraceAnnotation("probe.released"):
+                rt.release()
+                r = call("released")
+                gone = weakref.ref(r)
+                del r
+                kept = gone() is not None
+                rt.release()
+                seen["released"].update(kept_until_release=kept,
+                                        dropped=gone() is None)
+                call("released")
+            with TraceAnnotation("probe.no_runtime"):
+                r = call("no_runtime", runtime=None)
+                gone = weakref.ref(r)
+                del r
+                seen["no_runtime"]["dropped"] = gone() is None
+                call("no_runtime", runtime=None)
+            with TraceAnnotation("probe.device_operands"):
+                rt.release()
+                A, B, C = (jax.device_put(x, s) for x, s in zip(
+                    _operands(256, 256, 256, next(seeds)), rt.shardings()))
+                got = ooc_gemm(A, B, C, 1.0, 0.5, budget_bytes=2**30,
+                               backend="mesh", runtime=rt)
+                seen["device_operands"].update(
+                    type=type(got).__name__,
+                    on_devices=len(got.sharding.device_set),
+                    kept=rt._idle is not None)
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        events = [(e.name.split("#", 1)[0], int(e.start_ns), int(e.end_ns))
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name == "/host:CPU"
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith(("probe.", "ooc.mesh."))]
+
+    def within(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    results = [e for e in events if e[0].endswith("_result")]
+    for name in RECYCLED:
+        probe, = [e for e in events if e[0] == f"probe.{name}"]
+        gathers = sorted(e for e in events if e[0] == "ooc.mesh.gather"
+                         and within(e, probe))
+        seen[name]["gathers"] = [
+            [r[0] for r in sorted(results, key=lambda r: r[1])
+             if within(r, g)] for g in gathers]
+        seen[name]["results_outside_gathers"] = sum(
+            within(r, probe) and not any(within(r, g) for g in gathers)
+            for r in results)
+    return seen
 
 
 @pytest.fixture(scope="module")
@@ -254,6 +406,57 @@ def test_operands_the_ring_cannot_shard_are_refused(child, case):
     assert got is not None, "the call ran"
     assert got[0] == "ValueError"
     assert re.search(REFUSALS[case][2], got[1]), got[1]
+
+
+@pytest.mark.parametrize("scenario", RECYCLED)
+def test_recycled_results_match_the_plain_reference(child, scenario):
+    got = child["recycled"][scenario]
+    assert len(got["errs"]) == len(RECYCLED[scenario])
+    assert all(e <= 1.0 for e in got["errs"]), got["errs"]
+
+
+@pytest.mark.parametrize("scenario", RECYCLED)
+def test_one_result_span_a_call_inside_its_gather(child, scenario):
+    got = child["recycled"][scenario]
+    assert got["gathers"] == [[f"ooc.mesh.{choice}_result"]
+                              for choice in RECYCLED[scenario]]
+    assert got["results_outside_gathers"] == 0
+
+
+def test_a_dropped_result_is_gathered_into_again(child):
+    got = child["recycled"]["dropped"]
+    assert got["same_array"] and got["same_pointer"]
+
+
+@pytest.mark.parametrize("scenario", ("held", "view"))
+def test_a_held_result_or_view_is_never_written(child, scenario):
+    got = child["recycled"][scenario]
+    assert got["unchanged"] and not got["shares"]
+
+
+def test_a_result_passed_back_as_c_is_never_written_into(child):
+    assert child["recycled"]["passed_as_c"]["moved"] == [True, True]
+
+
+def test_a_new_shape_drops_the_kept_result(child):
+    got = child["recycled"]["new_shape"]
+    assert got["kept_until_next_call"] and got["dropped"]
+    assert got["shape"] == [128, 256]
+
+
+def test_release_drops_the_kept_result(child):
+    got = child["recycled"]["released"]
+    assert got["kept_until_release"] and got["dropped"]
+
+
+def test_without_a_runtime_nothing_is_kept(child):
+    assert child["recycled"]["no_runtime"]["dropped"]
+
+
+def test_device_operands_leave_the_runtime_keeping_nothing(child):
+    got = child["recycled"]["device_operands"]
+    assert got["type"] == "ArrayImpl" and got["on_devices"] == DEVICES
+    assert not got["kept"]
 
 
 if __name__ == "__main__":
